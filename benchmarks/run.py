@@ -835,18 +835,24 @@ def bench_mixed(quick: bool = False):
 # ------------------------------------------------- SPMD mesh-executor ring
 
 
-def bench_prefill_spmd(quick: bool = False):
-    """Mesh-executor ring prefill on an 8-virtual-device host mesh: the
-    DoP>1 packed prefill as ONE shard_map program with the KV stripes
-    ppermuted between devices — double-buffered vs sequential ring vs the
-    in-process LocalExecutor replay, plus exact per-ring-step ppermute
-    bytes.  Runs in a subprocess because the device-count XLA flag must be
-    set before jax initializes.  Writes BENCH_prefill_spmd.json."""
+def _cpu_rehearsal(module: str, quick: bool) -> None:
+    """Run one SPMD bench body in a child process on 8 CPU virtual devices.
+
+    These benches are CPU virtual-device rehearsals: the child needs the
+    host-device-count flag set before jax initializes, so it must be its own
+    process, and a child must never need the chip a parent holds.  They
+    therefore run only when this driver runs under ``JAX_PLATFORMS=cpu``
+    (elsewhere they print a SKIP row); the child inherits that setting."""
     import os
     import pathlib
     import subprocess
     import sys
 
+    name = module.rsplit(".", 1)[-1]
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        _row(name, 0.0, "SKIP:CPU virtual-device rehearsal, needs "
+             "JAX_PLATFORMS=cpu")
+        return
     root = pathlib.Path(__file__).parent.parent
     # the child module self-appends the 8-device XLA flag before jax
     # initializes; only PYTHONPATH needs to be threaded through here
@@ -854,7 +860,7 @@ def bench_prefill_spmd(quick: bool = False):
     env["PYTHONPATH"] = str(root / "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
     )
-    cmd = [sys.executable, "-m", "benchmarks.prefill_spmd"]
+    cmd = [sys.executable, "-m", module]
     if quick:
         cmd.append("--quick")
     out = subprocess.run(cmd, env=env, cwd=root, capture_output=True,
@@ -862,10 +868,21 @@ def bench_prefill_spmd(quick: bool = False):
     if out.returncode != 0:
         raise RuntimeError(out.stdout + "\n" + out.stderr)
     row = next(
-        ln for ln in out.stdout.splitlines() if ln.startswith("prefill_spmd,")
+        ln for ln in out.stdout.splitlines() if ln.startswith(name + ",")
     )
     _, us, derived = row.split(",", 2)
-    _row("prefill_spmd", float(us), derived)
+    _row(name, float(us), derived)
+
+
+def bench_prefill_spmd(quick: bool = False):
+    """Mesh-executor ring prefill on an 8-virtual-device host mesh: the
+    DoP>1 packed prefill as ONE shard_map program with the KV stripes
+    ppermuted between devices — double-buffered vs sequential ring vs the
+    in-process LocalExecutor replay, plus exact per-ring-step ppermute
+    bytes.  A CPU virtual-device rehearsal (`_cpu_rehearsal`): it runs in
+    a child process, only under ``JAX_PLATFORMS=cpu``, so no parent that
+    holds the chip ever spawns it.  Writes BENCH_prefill_spmd.json."""
+    _cpu_rehearsal("benchmarks.prefill_spmd", quick)
 
 
 # ------------------------------------------------ SPMD mesh-executor decode
@@ -878,33 +895,11 @@ def bench_decode_spmd(quick: bool = False):
     boundary, in-program sampling) vs the replicated overlapped/barriered
     programs vs the per-shard Python loop with explicit device hops — plus
     per-iteration collective payload bytes, structural StableHLO overlap
-    evidence and the ~1/n dot-FLOP census ratio.  Runs in a subprocess
-    because the device-count XLA flag must be set before jax initializes.
+    evidence and the ~1/n dot-FLOP census ratio.  A CPU virtual-device
+    rehearsal (`_cpu_rehearsal`): it runs in a child process, only under
+    ``JAX_PLATFORMS=cpu``, so no parent that holds the chip ever spawns it.
     Writes BENCH_decode_spmd.json."""
-    import os
-    import pathlib
-    import subprocess
-    import sys
-
-    root = pathlib.Path(__file__).parent.parent
-    # the child module self-appends the 8-device XLA flag before jax
-    # initializes; only PYTHONPATH needs to be threaded through here
-    env = dict(os.environ)
-    env["PYTHONPATH"] = str(root / "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
-    )
-    cmd = [sys.executable, "-m", "benchmarks.decode_spmd"]
-    if quick:
-        cmd.append("--quick")
-    out = subprocess.run(cmd, env=env, cwd=root, capture_output=True,
-                         text=True, timeout=3600)
-    if out.returncode != 0:
-        raise RuntimeError(out.stdout + "\n" + out.stderr)
-    row = next(
-        ln for ln in out.stdout.splitlines() if ln.startswith("decode_spmd,")
-    )
-    _, us, derived = row.split(",", 2)
-    _row("decode_spmd", float(us), derived)
+    _cpu_rehearsal("benchmarks.decode_spmd", quick)
 
 
 # -------------------------------------------------------------- roofline
@@ -1057,6 +1052,7 @@ def main() -> None:
     if args.smoke:
         args.quick = True
     print("name,us_per_call,derived")
+    errors = []
     for name, fn in BENCHES.items():
         if args.smoke and name not in SMOKE:
             continue
@@ -1068,6 +1064,10 @@ def main() -> None:
             if args.smoke:
                 raise
             _row(name, 0.0, f"ERROR:{type(e).__name__}:{e}")
+            errors.append(name)
+    if errors:
+        print(f"benchmarks failed: {', '.join(errors)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -216,9 +216,9 @@ def ring_packed_prefill_spmd(
     The per-shard segment offsets are static metadata derived from the
     REPLICATED global ``seq_offsets`` inside the body (`striped
     .shard_offsets` with the traced rank / chunk provenance) rather than fed
-    as a data-sharded [n, B+1] array: jax 0.4.x's SPMD partitioner
-    mis-reshards tiny computed arrays entering a manual region on a
-    multi-axis mesh, and the ring leg then only needs to move KV bytes.
+    as a data-sharded [n, B+1] array: the offsets are a few bytes every
+    rank can derive for any shard, and the ring leg then only needs to
+    move KV bytes.
 
     Shard ids reach the chunk kernel rank-derived, so the real (Pallas)
     kernel dispatches through `switched_ring_chunk`'s statically-specialized

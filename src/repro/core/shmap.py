@@ -1,23 +1,12 @@
-"""`shard_map` across jax versions (single shim for every SPMD module).
-
-jax >= 0.6 exposes `jax.shard_map` (replication checking via ``check_vma``);
-older releases ship it as `jax.experimental.shard_map.shard_map` with the
-equivalent ``check_rep`` flag.  Every shard_map body in this repo uses manual
-collectives with unannotated replication, so checking is disabled on both.
-"""
+"""`jax.shard_map` with replication checking off (single shim for every SPMD
+module): every shard_map body in this repo uses manual collectives with
+unannotated replication, so ``check_vma`` is disabled."""
 from __future__ import annotations
 
 import jax
 
 
 def shmap(fn, mesh, in_specs, out_specs):
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False,
     )

@@ -1,15 +1,16 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
 impl:
-  * "xla"        — pure-jnp reference math (the dry-run / SPMD path; XLA fuses
-                   it well enough on CPU and is the portable fallback on TPU);
+  * "xla"        — pure-jnp reference math (the CPU path and the SPMD
+                   dry-run; it also takes traced shard ids directly);
   * "pallas"     — the Pallas TPU kernel (compiled for TPU);
   * "interpret"  — the Pallas kernel body executed in interpret mode (CPU
                    validation of the TPU kernel).
 
-The default impl can be selected without code edits via the
-``REPRO_KERNEL_IMPL`` environment variable (benchmarks / CI), and overridden
-programmatically with `set_default_impl`.
+The default impl follows the backend: "pallas" on a TPU, "xla" elsewhere.
+It is decided at the first dispatch (not at import, so the backend is the
+one the process really runs on).  ``REPRO_KERNEL_IMPL`` overrides it without
+code edits (benchmarks / CI), and `set_default_impl` programmatically.
 
 `dispatch_counts` tracks kernel/dispatch call volume per entry point so tests
 and benchmarks can assert launch-count invariants (e.g. one paged decode
@@ -69,8 +70,12 @@ def check_fault(point: str) -> None:
         _fault_hook(point)
 
 
-def _impl_from_env() -> str:
-    impl = os.environ.get("REPRO_KERNEL_IMPL", "xla")
+def _resolve_default_impl() -> str:
+    """``REPRO_KERNEL_IMPL`` if set, else the backend's own: the Pallas
+    kernels on a TPU, the XLA reference math anywhere else."""
+    impl = os.environ.get("REPRO_KERNEL_IMPL")
+    if impl is None:
+        return "pallas" if jax.default_backend() == "tpu" else "xla"
     if impl not in _VALID_IMPLS:
         raise ValueError(
             f"REPRO_KERNEL_IMPL={impl!r}: expected one of {_VALID_IMPLS}"
@@ -78,7 +83,7 @@ def _impl_from_env() -> str:
     return impl
 
 
-_DEFAULT_IMPL = _impl_from_env()
+_DEFAULT_IMPL: Optional[str] = None  # resolved at the first dispatch
 
 dispatch_counts: Counter = Counter()
 
@@ -101,6 +106,9 @@ def set_default_impl(impl: str) -> None:
 
 
 def get_default_impl() -> str:
+    global _DEFAULT_IMPL
+    if _DEFAULT_IMPL is None:
+        _DEFAULT_IMPL = _resolve_default_impl()
     return _DEFAULT_IMPL
 
 
@@ -108,7 +116,7 @@ def attention(
     q, k, v, q_pos, k_pos, *, causal=True, window=None, softcap=None,
     impl: Optional[str] = None, block_q: int = 128, block_k: int = 128,
 ):
-    impl = impl or _DEFAULT_IMPL
+    impl = impl or get_default_impl()
     check_fault("attention")
     dispatch_counts["attention"] += 1
     if impl == "xla":
@@ -127,7 +135,7 @@ def decode_partial(
     impl: Optional[str] = None, block_k: int = 128,
 ) -> Partial:
     """Per-request decode over a dense KV shard (legacy gather-dense path)."""
-    impl = impl or _DEFAULT_IMPL
+    impl = impl or get_default_impl()
     check_fault("decode_partial")
     dispatch_counts["decode_partial"] += 1
     if impl == "xla":
@@ -149,7 +157,7 @@ def prefill_packed(
     concatenated on a single token axis (see kernels/paged_flash_prefill.py).
     ``max_seq_len`` (static) bounds the banded XLA fallback's reach; the
     Pallas kernel skips non-interacting tiles from the prefetched offsets."""
-    impl = impl or _DEFAULT_IMPL
+    impl = impl or get_default_impl()
     check_fault("prefill_packed")
     dispatch_counts["prefill_packed"] += 1
     if impl == "xla":
@@ -179,7 +187,7 @@ def prefill_ring_chunk(
     from; causal/window masks evaluate on global striped positions.
     ``carry=None`` starts an empty state (m=-inf).  Finalize after the last
     step with ``o / l`` (l==0 rows are bucket padding)."""
-    impl = impl or _DEFAULT_IMPL
+    impl = impl or get_default_impl()
     check_fault("prefill_ring_chunk")
     dispatch_counts["prefill_ring_chunk"] += 1
     if carry is None:
@@ -290,7 +298,7 @@ def paged_decode_partial(
 ) -> Partial:
     """Batched ragged decode over the paged pool: ONE launch for every
     request of this instance (see kernels/paged_flash_decode.py)."""
-    impl = impl or _DEFAULT_IMPL
+    impl = impl or get_default_impl()
     check_fault("paged_decode_partial")
     dispatch_counts["paged_decode_partial"] += 1
     if impl == "xla":

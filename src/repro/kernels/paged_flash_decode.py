@@ -27,6 +27,18 @@ kernel takes explicit ``query_pos`` and per-slot global positions
 (``page_pos``) and applies ``query_pos - page_pos < window``.  Causality
 needs no mask here: every pooled token precedes the query by construction.
 
+Block layout (what Mosaic accepts at real widths): a TPU block's last two
+dims must be multiples of (8, 128) or span the whole array, so a per-head
+page block ``(1, P, 1, D)`` is refused.  The kernel instead views the page
+storage as ``[n_pages, P*KVH, D]`` — the same bytes whenever KVH is a
+multiple of the sublane tile, so the view is free — and streams one WHOLE
+page (all heads) per grid step.  Every query head scores every row of the
+page in one ``[H, D] x [D, P*KVH]`` matmul and a head-match mask (column
+``c`` is token ``c // KVH`` of KV head ``c % KVH``) keeps each query head
+on its own KV head; ``p @ v`` then sums only the matching rows.  The
+matmul does KVH-fold redundant MXU work, which decode (bound by reading
+the page) hides.  Stats leave as ``[B, H, 1]`` columns.
+
 Emits the unnormalized Partial(o, m, l) for ALL requests in one launch; the
 ESP multi-master combine (attention.merge_partial) merges partials across
 instances exactly as before — scaling migration stays zero-copy.
@@ -54,6 +66,8 @@ def _kernel(
     window: Optional[int],
     softcap: Optional[float],
     page_size: int,
+    kvh: int,
+    q_per_kv: int,
     n_page_blocks: int,
 ):
     if window is not None:
@@ -61,7 +75,7 @@ def _kernel(
     else:
         o_ref, m_out_ref, l_out_ref, acc_ref, m_ref, l_ref = rest
     b = pl.program_id(0)
-    ip = pl.program_id(2)
+    ip = pl.program_id(1)
 
     @pl.when(ip == 0)
     def _init():
@@ -69,46 +83,46 @@ def _kernel(
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    qb = q_ref[0, 0, :, :].astype(jnp.float32)  # [H_blk, D] (q heads block)
-    kb = k_ref[0, :, 0, :].astype(jnp.float32)  # [P, D] one page
-    vb = v_ref[0, :, 0, :].astype(jnp.float32)
+    qb = q_ref[0, 0].astype(jnp.float32)  # [H, D]
+    kb = k_ref[0].astype(jnp.float32)  # [P*KVH, D] one whole page
+    vb = v_ref[0].astype(jnp.float32)
     s = jax.lax.dot_general(
         qb, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # [H_blk, P]
+    ) * scale  # [H, P*KVH]
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
 
-    n_local = len_ref[b]  # this request's ragged local token count
-    j_local = ip * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (s.shape[0], page_size), 1
-    )
-    mask = j_local < n_local  # masked tail page (+ padding pages entirely)
+    shape = s.shape
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    head = col % kvh  # column c = (token c // KVH, kv head c % KVH)
+    mask = (row >= head * q_per_kv) & (row < (head + 1) * q_per_kv)
+    # masked tail page (+ padding pages entirely)
+    mask &= ip * page_size + col // kvh < len_ref[b]
     if window is not None:
-        kp = pos_ref[0, :].astype(jnp.int32)  # [P] global positions
-        mask &= (qp_ref[b] - kp[None, :]) < window
+        kp = pos_ref[0]  # [1, P*KVH] global position per column
+        mask &= (qp_ref[b] - kp) < window
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[:, 0]
-    l_prev = l_ref[:, 0]
-    m_blk = jnp.max(s, axis=1)
+    m_prev = m_ref[...]  # [H, 1]
+    l_prev = l_ref[...]
+    m_blk = jnp.max(s, axis=1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_blk)
     m_safe = jnp.maximum(m_new, -1e29)  # fully-masked-row guard
-    p = jnp.exp(s - m_safe[:, None])
-    p = jnp.where(mask, p, 0.0)
+    p = jnp.where(mask, jnp.exp(s - m_safe), 0.0)
     alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, jnp.exp(m_prev - m_safe))
-    l_new = alpha * l_prev + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+    l_ref[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p, vb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
-    m_ref[:, 0] = jnp.where(m_blk <= NEG_INF / 2, m_prev, m_new)
-    l_ref[:, 0] = l_new
+    m_ref[...] = jnp.where(m_blk <= NEG_INF / 2, m_prev, m_new)
 
     @pl.when(ip == n_page_blocks - 1)
     def _emit():
-        o_ref[0, 0, :, :] = acc_ref[...]
-        mm = m_ref[:, 0]
-        m_out_ref[0, 0, :] = jnp.where(mm <= NEG_INF / 2, -jnp.inf, mm)
-        l_out_ref[0, 0, :] = l_ref[:, 0]
+        o_ref[0, 0] = acc_ref[...]
+        mm = m_ref[...]
+        m_out_ref[0] = jnp.where(mm <= NEG_INF / 2, -jnp.inf, mm)
+        l_out_ref[0] = l_ref[...]
 
 
 def paged_flash_decode_partial(
@@ -129,7 +143,7 @@ def paged_flash_decode_partial(
     b, sq, h, d = q.shape
     assert sq == 1, "decode kernel: one query token per request"
     n_pages, page_size, kvh = k_pages.shape[0], k_pages.shape[1], k_pages.shape[2]
-    q_per_kv = h // kvh
+    rows = page_size * kvh
     max_pages = block_table.shape[1]
     if max_pages == 0:
         return empty_partial(b, sq, h, d)
@@ -139,56 +153,47 @@ def paged_flash_decode_partial(
         )
     if query_pos is None:
         query_pos = jnp.zeros((b,), jnp.int32)
-    scale = 1.0 / math.sqrt(d)
 
     kernel = functools.partial(
-        _kernel, scale=scale, window=window, softcap=softcap,
-        page_size=page_size, n_page_blocks=max_pages,
+        _kernel, scale=1.0 / math.sqrt(d), window=window, softcap=softcap,
+        page_size=page_size, kvh=kvh, q_per_kv=h // kvh,
+        n_page_blocks=max_pages,
+    )
+    page_spec = pl.BlockSpec(
+        (1, rows, d), lambda b_, ip, bt, ln, qp: (bt[b_, ip], 0, 0)
     )
     in_specs = [
-        # q heads for this kv group: [1, 1, q_per_kv, D]
-        pl.BlockSpec(
-            (1, 1, q_per_kv, d),
-            lambda b_, g, ip, bt, ln, qp: (b_, 0, g, 0),
-        ),
-        # one KV page, routed by the prefetched block table
-        pl.BlockSpec(
-            (1, page_size, 1, d),
-            lambda b_, g, ip, bt, ln, qp: (bt[b_, ip], 0, g, 0),
-        ),
-        pl.BlockSpec(
-            (1, page_size, 1, d),
-            lambda b_, g, ip, bt, ln, qp: (bt[b_, ip], 0, g, 0),
-        ),
+        pl.BlockSpec((1, 1, h, d), lambda b_, ip, bt, ln, qp: (b_, 0, 0, 0)),
+        page_spec,
+        page_spec,
     ]
-    operands = [q, k_pages, v_pages]
+    operands = [
+        q, k_pages.reshape(n_pages, rows, d), v_pages.reshape(n_pages, rows, d)
+    ]
     if window is not None:
         # per-slot positions ride along ONLY when windowed — unwindowed
-        # decode skips the O(capacity) pos upload/DMA entirely
+        # decode skips the O(capacity) pos upload/DMA entirely; repeated per
+        # KV head to match the page view's columns
         in_specs.append(pl.BlockSpec(
-            (1, page_size),
-            lambda b_, g, ip, bt, ln, qp: (bt[b_, ip], 0),
+            (1, 1, rows), lambda b_, ip, bt, ln, qp: (bt[b_, ip], 0, 0),
         ))
-        operands.append(jnp.asarray(page_pos, jnp.int32))
+        operands.append(jnp.repeat(
+            jnp.asarray(page_pos, jnp.int32), kvh, axis=1
+        ).reshape(n_pages, 1, rows))
+    stat_spec = pl.BlockSpec((1, h, 1), lambda b_, ip, bt, ln, qp: (b_, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # block_table, lengths, query_pos
-        grid=(b, kvh, max_pages),
+        grid=(b, max_pages),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec(
-                (1, 1, q_per_kv, d), lambda b_, g, ip, bt, ln, qp: (b_, 0, g, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, q_per_kv), lambda b_, g, ip, bt, ln, qp: (b_, 0, g)
-            ),
-            pl.BlockSpec(
-                (1, 1, q_per_kv), lambda b_, g, ip, bt, ln, qp: (b_, 0, g)
-            ),
+            pl.BlockSpec((1, 1, h, d), lambda b_, ip, bt, ln, qp: (b_, 0, 0, 0)),
+            stat_spec,
+            stat_spec,
         ],
         scratch_shapes=[
-            pltpu.VMEM((q_per_kv, d), jnp.float32),
-            pltpu.VMEM((q_per_kv, 1), jnp.float32),
-            pltpu.VMEM((q_per_kv, 1), jnp.float32),
+            pltpu.VMEM((h, d), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
         ],
     )
     o, m, l = pl.pallas_call(
@@ -196,8 +201,8 @@ def paged_flash_decode_partial(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, 1, h, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, 1, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, 1, h), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, 1), jnp.float32),
         ],
         interpret=interpret,
     )(
@@ -206,4 +211,4 @@ def paged_flash_decode_partial(
         jnp.asarray(query_pos, jnp.int32),
         *operands,
     )
-    return Partial(o=o, m=m, l=l)
+    return Partial(o=o, m=m.reshape(b, 1, h), l=l.reshape(b, 1, h))

@@ -2,7 +2,7 @@
 
 One launch serves EVERY prefill request of a batch: the prompts are
 concatenated ("packed") along a single token axis of bucketed length T, and
-the grid runs over ``(kv_head_group, q_block, k_block)``.  Per-sequence
+the grid runs over ``(q_block, kv_head_group, k_block)``.  Per-sequence
 boundaries ride in through a scalar-prefetched offsets array — available in
 SMEM before the kernel body runs — so each program derives segment ids for
 its q/k tiles and (a) skips tiles whose segment ranges cannot interact and
@@ -43,6 +43,21 @@ pair is skipped when its global causal reach, segment ranges, or window reach
 cannot interact.  After n steps the carried state finalizes to exactly the
 single-launch packed result (same math, chunked).
 
+Block layout (what Mosaic accepts at real widths)
+-------------------------------------------------
+A TPU block's last two dims must be multiples of (8, 128) or span the
+whole array, so a per-head block of a ``[T, H, D]`` operand (second-minor
+dim 1 or ``q_per_kv``) is refused.  The kernels therefore view the
+operands as ``[T, H*D]`` / ``[T, KVH*D]`` (a free reshape of the
+projections' output) and block one KV group's columns: q and the output
+``(block_q, q_per_kv*D)``, k/v ``(block_k, D)`` — lane-aligned whenever
+``q_per_kv*D`` and ``D`` are multiples of 128.  The per-row softmax stats
+of ALL heads live in one ``(block_q, H)`` VMEM tile (full last dim); the
+grid runs ``(q_block, kv_group, k_block)`` so that tile, and the ring
+variant's ``(block_q, H)`` stat blocks of the carried state, stay resident
+across the groups of one q block.  A group's columns are read and written
+through one-hot lane masks, so no dynamic lane slicing is needed.
+
 Deployment note: the in-process replay (LocalExecutor) passes static shard
 ids, so this Pallas kernel applies directly.  The shard_map mesh path
 (`core.esp.ring_packed_prefill_spmd`) has TRACED shard ids
@@ -52,10 +67,7 @@ enumerates one branch per rank (the ring step is a python loop constant),
 each baking ``q_shard=rank, k_shard=(rank-step) % n`` as the compile-time
 constants the tile-skip predicates need.  Under ``impl="xla"`` the banded
 variant (`ref.packed_prefill_ring_chunk_banded`, shard ids as jnp values)
-still dispatches directly with no switch.  The switch path is validated
-under ``impl="interpret"`` in the mesh suite; running it compiled on real
-TPU hardware (each branch lowering to this Pallas kernel) is the remaining
-ROADMAP item — hardware validation only, the program structure is in.
+still dispatches directly with no switch.
 """
 from __future__ import annotations
 
@@ -71,90 +83,192 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(
-    off_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-    scale: float,
-    window: Optional[int],
-    softcap: Optional[float],
-    block_q: int,
-    block_k: int,
-    n_seqs: int,
-    n_k_blocks: int,
-):
-    iq = pl.program_id(1)
+def _block(n: int, want: int) -> int:
+    """Largest power-of-two fraction of ``want`` dividing ``n`` (or ``n``
+    itself when it is smaller): the 3/4-point token buckets (3*2^j) halve
+    to a divisor."""
+    blk = min(want, n)
+    while n % blk:
+        blk //= 2
+    assert blk >= 1, (n, want)
+    return blk
+
+
+def _seg_scalar(j, off_ref, n_seqs: int):
+    """Segment id of one (scalar) local index from prefetched offsets."""
+    return jax.lax.fori_loop(
+        0, n_seqs,
+        lambda b, acc: acc + (j >= off_ref[b + 1]).astype(jnp.int32),
+        jnp.int32(0),
+    )
+
+
+def _seg_vector(j, off_ref, n_seqs: int):
+    """Segment id per element of an int32 index tile (monotone in j)."""
+    return jax.lax.fori_loop(
+        0, n_seqs,
+        lambda b, acc: acc + jnp.where(j >= off_ref[b + 1], 1, 0),
+        jnp.zeros_like(j),
+    )
+
+
+def _kernel(*refs, scale: float, window: Optional[int],
+            softcap: Optional[float], q_shard: int, k_shard: int,
+            n_shards: int, block_q: int, block_k: int, n_seqs: int,
+            n_k_blocks: int, q_per_kv: int, d: int, carry: bool):
+    """Shared body of the packed kernel (``carry=False``: empty start state,
+    normalized output) and the ring chunk (``carry=True``: resume and emit
+    the unnormalized (acc, m, l) state).  Grid: (q block, kv group, k block).
+    Shard ids / count are static; the packed kernel is shard 0 of 1."""
+    if carry:
+        (qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_in_ref, m_in_ref,
+         l_in_ref, o_ref, m_out_ref, l_out_ref, acc_ref, m_ref, l_ref) = refs
+    else:
+        (qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref,
+         acc_ref, m_ref, l_ref) = refs
+    iq = pl.program_id(0)
+    g = pl.program_id(1)
     ik = pl.program_id(2)
+    n = n_shards
+
+    @pl.when((g == 0) & (ik == 0))
+    def _init_stats():  # stats of all heads, resident across the groups
+        if carry:
+            m_ref[...] = m_in_ref[...]
+            l_ref[...] = l_in_ref[...]
+        else:
+            m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+            l_ref[...] = jnp.zeros_like(l_ref)
 
     @pl.when(ik == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def _init_acc():
+        if carry:
+            acc_ref[...] = o_in_ref[...]
+        else:
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # packed token indices of this tile pair
-    tq = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
-    tk = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-
-    def seg_ids(t):
-        """Segment id per packed index: #offsets[1..B] <= t (monotone)."""
-
-        def body(b, acc):
-            return acc + jnp.where(t >= off_ref[b + 1], 1, 0)
-
-        return jax.lax.fori_loop(0, n_seqs, body, jnp.zeros_like(t))
-
-    seg_q = seg_ids(tq)  # [block_q, 1]
-    seg_k = seg_ids(tk)  # [1, block_k]
-
-    # tile-level skip: causal reach, segment-range overlap (seg ids are
-    # monotone in t, so ranges are the tile corners), window reach
-    run = ik * block_k <= iq * block_q + block_q - 1
-    run &= (seg_k[0, 0] <= seg_q[block_q - 1, 0]) & (
-        seg_q[0, 0] <= seg_k[0, block_k - 1]
-    )
+    # tile-level skip in GLOBAL striped coordinates (shard r's local slot j
+    # is packed index j*n + r): causal reach, segment-range overlap (the
+    # per-shard seg ids are monotone in the local index), window reach
+    q_lo, k_lo = iq * block_q, ik * block_k
+    q_hi, k_hi = q_lo + block_q - 1, k_lo + block_k - 1
+    run = k_lo * n + k_shard <= q_hi * n + q_shard
+    run &= _seg_scalar(k_lo, koff_ref, n_seqs) <= _seg_scalar(
+        q_hi, qoff_ref, n_seqs)
+    run &= _seg_scalar(q_lo, qoff_ref, n_seqs) <= _seg_scalar(
+        k_hi, koff_ref, n_seqs)
     if window is not None:
-        run &= (iq * block_q - (ik * block_k + block_k - 1)) < window
+        run &= (q_lo * n + q_shard) - (k_hi * n + k_shard) < window
 
     @pl.when(run)
     def _update():
-        qpk = q_ref.shape[1]
-        qb = q_ref[...].astype(jnp.float32).reshape(block_q * qpk, -1)
-        kb = k_ref[:, 0, :].astype(jnp.float32)  # [block_k, D]
-        vb = v_ref[:, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [block_q * qpk, block_k]
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
-        mask = (seg_q == seg_k) & (tq >= tk)
+        jq = q_lo + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+        jk = k_lo + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+        gq, gk = jq * n + q_shard, jk * n + k_shard
+        mask = (_seg_vector(jq, qoff_ref, n_seqs)
+                == _seg_vector(jk, koff_ref, n_seqs)) & (gq >= gk)
         if window is not None:
-            mask &= (tq - tk) < window
-        mask = jnp.broadcast_to(
-            mask[:, None, :], (block_q, qpk, block_k)
-        ).reshape(block_q * qpk, block_k)
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_ref[:, 0]
-        l_prev = l_ref[:, 0]
-        m_blk = jnp.max(s, axis=1)
-        m_new = jnp.maximum(m_prev, m_blk)
-        m_safe = jnp.maximum(m_new, -1e29)  # fully-masked-row guard
-        p = jnp.exp(s - m_safe[:, None])
-        p = jnp.where(mask, p, 0.0)
-        alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, jnp.exp(m_prev - m_safe))
-        l_new = alpha * l_prev + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:, 0] = jnp.where(m_blk <= NEG_INF / 2, m_prev, m_new)
-        l_ref[:, 0] = l_new
+            mask &= (gq - gk) < window
+        kb = k_ref[...].astype(jnp.float32)  # [block_k, D]
+        vb = v_ref[...].astype(jnp.float32)
+        m_all, l_all = m_ref[...], l_ref[...]  # [block_q, H]
+        lane = jax.lax.broadcasted_iota(jnp.int32, m_all.shape, 1)
+        for hh in range(q_per_kv):
+            sel = lane == g * q_per_kv + hh  # this head's stat column
+            cols = slice(hh * d, (hh + 1) * d)
+            qh = q_ref[:, cols].astype(jnp.float32)  # [block_q, D]
+            s = jax.lax.dot_general(
+                qh, kb, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [block_q, block_k]
+            if softcap is not None:
+                s = softcap * jnp.tanh(s / softcap)
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev = jnp.max(jnp.where(sel, m_all, -jnp.inf), axis=1,
+                             keepdims=True)
+            l_prev = jnp.sum(jnp.where(sel, l_all, 0.0), axis=1,
+                             keepdims=True)
+            m_blk = jnp.max(s, axis=1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_blk)
+            m_safe = jnp.maximum(m_new, -1e29)  # fully-masked-row guard
+            p = jnp.where(mask, jnp.exp(s - m_safe), 0.0)
+            alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0,
+                              jnp.exp(m_prev - m_safe))
+            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[:, cols] = acc_ref[:, cols] * alpha + jax.lax.dot_general(
+                p, vb, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_keep = jnp.where(m_blk <= NEG_INF / 2, m_prev, m_new)
+            m_all = jnp.where(sel, m_keep, m_all)
+            l_all = jnp.where(sel, l_new, l_all)
+        m_ref[...] = m_all
+        l_ref[...] = l_all
 
     @pl.when(ik == n_k_blocks - 1)
     def _emit():
-        l = l_ref[:, 0]
-        denom = jnp.where(l == 0.0, 1.0, l)
-        o_ref[...] = (acc_ref[...] / denom[:, None]).reshape(o_ref.shape)
+        if carry:  # UNNORMALIZED: the state continues to the next ring step
+            o_ref[...] = acc_ref[...]
+            m_out_ref[...] = m_ref[...]
+            l_out_ref[...] = l_ref[...]
+            return
+        l_all = l_ref[...]
+        lane = jax.lax.broadcasted_iota(jnp.int32, l_all.shape, 1)
+        for hh in range(q_per_kv):
+            l_h = jnp.sum(jnp.where(lane == g * q_per_kv + hh, l_all, 0.0),
+                          axis=1, keepdims=True)
+            cols = slice(hh * d, (hh + 1) * d)
+            o_ref[:, cols] = acc_ref[:, cols] / jnp.where(l_h == 0.0, 1.0, l_h)
+
+
+def _call(q, k, v, q_offsets, k_offsets, carry, *, q_shard, k_shard,
+          n_shards, window, softcap, block_q, block_k, interpret):
+    """Shared pallas_call for both entry points (see `_kernel`)."""
+    t, h, d = q.shape
+    kvh = k.shape[1]
+    q_per_kv = h // kvh
+    block_q, block_k = _block(t, block_q), _block(t, block_k)
+    nq, nk = t // block_q, t // block_k
+    n_seqs = int(q_offsets.shape[0]) - 1
+    kernel = functools.partial(
+        _kernel, scale=1.0 / math.sqrt(d), window=window, softcap=softcap,
+        q_shard=q_shard, k_shard=k_shard, n_shards=n_shards,
+        block_q=block_q, block_k=block_k, n_seqs=n_seqs, n_k_blocks=nk,
+        q_per_kv=q_per_kv, d=d, carry=carry is not None,
+    )
+    grp = q_per_kv * d
+    q_spec = pl.BlockSpec((block_q, grp), lambda iq, g, ik, qo, ko: (iq, g))
+    kv_spec = pl.BlockSpec((block_k, d), lambda iq, g, ik, qo, ko: (ik, g))
+    stat_spec = pl.BlockSpec((block_q, h), lambda iq, g, ik, qo, ko: (iq, 0))
+    in_specs = [q_spec, kv_spec, kv_spec]
+    operands = [q.reshape(t, h * d), k.reshape(t, kvh * d),
+                v.reshape(t, kvh * d)]
+    out_shape = [jax.ShapeDtypeStruct((t, h * d), jnp.float32)]
+    out_specs = [q_spec]
+    if carry is not None:
+        o_c, m_c, l_c = carry
+        in_specs += [q_spec, stat_spec, stat_spec]
+        operands += [jnp.asarray(o_c, jnp.float32).reshape(t, h * d),
+                     jnp.asarray(m_c, jnp.float32),
+                     jnp.asarray(l_c, jnp.float32)]
+        out_shape += [jax.ShapeDtypeStruct((t, h), jnp.float32)] * 2
+        out_specs += [stat_spec, stat_spec]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,  # q_offsets, k_offsets
+        grid=(nq, kvh, nk),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=[
+            pltpu.VMEM((block_q, grp), jnp.float32),
+            pltpu.VMEM((block_q, h), jnp.float32),
+            pltpu.VMEM((block_q, h), jnp.float32),
+        ],
+    )
+    outs = pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interpret,
+    )(jnp.asarray(q_offsets, jnp.int32), jnp.asarray(k_offsets, jnp.int32),
+      *operands)
+    return (outs[0].reshape(t, h, d),) + tuple(outs[1:])
 
 
 def packed_flash_prefill(
@@ -171,150 +285,14 @@ def packed_flash_prefill(
 ) -> jnp.ndarray:
     """One ragged batched launch over the packed token axis; returns the
     normalized attention output [T, H, D] (f32)."""
-    t, h, d = q.shape
-    kvh = k.shape[1]
-    q_per_kv = h // kvh
-    block_q = min(block_q, t)
-    block_k = min(block_k, t)
-    while t % block_q:  # 3/4-point buckets (e.g. 3*2^j): halve to a divisor
-        block_q //= 2
-    while t % block_k:
-        block_k //= 2
-    assert block_q >= 1 and block_k >= 1, (t, block_q, block_k)
-    n_seqs = int(seq_offsets.shape[0]) - 1
-    nq, nk = t // block_q, t // block_k
-    scale = 1.0 / math.sqrt(d)
-
-    kernel = functools.partial(
-        _kernel, scale=scale, window=window, softcap=softcap,
-        block_q=block_q, block_k=block_k, n_seqs=n_seqs, n_k_blocks=nk,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,  # seq_offsets
-        grid=(kvh, nq, nk),
-        in_specs=[
-            # q heads for this kv group: [block_q, q_per_kv, D]
-            pl.BlockSpec(
-                (block_q, q_per_kv, d), lambda g, iq, ik, off: (iq, g, 0)
-            ),
-            pl.BlockSpec((block_k, 1, d), lambda g, iq, ik, off: (ik, g, 0)),
-            pl.BlockSpec((block_k, 1, d), lambda g, iq, ik, off: (ik, g, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (block_q, q_per_kv, d), lambda g, iq, ik, off: (iq, g, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((block_q * q_per_kv, d), jnp.float32),
-            pltpu.VMEM((block_q * q_per_kv, 1), jnp.float32),
-            pltpu.VMEM((block_q * q_per_kv, 1), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, h, d), jnp.float32),
-        interpret=interpret,
-    )(jnp.asarray(seq_offsets, jnp.int32), q, k, v)
+    return _call(
+        q, k, v, seq_offsets, seq_offsets, None, q_shard=0, k_shard=0,
+        n_shards=1, window=window, softcap=softcap, block_q=block_q,
+        block_k=block_k, interpret=interpret,
+    )[0]
 
 
 # ===================================================== ring-fused chunk step
-
-
-def _ring_kernel(
-    qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_in_ref, m_in_ref, l_in_ref,
-    o_ref, m_out_ref, l_out_ref, acc_ref, m_ref, l_ref, *,
-    scale: float,
-    window: Optional[int],
-    softcap: Optional[float],
-    q_shard: int,
-    k_shard: int,
-    n_shards: int,
-    block_q: int,
-    block_k: int,
-    n_seqs: int,
-    n_k_blocks: int,
-):
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
-    n = n_shards
-
-    @pl.when(ik == 0)
-    def _init():  # resume the carried flash state (m=-inf empty on step 0)
-        acc_ref[...] = o_in_ref[...].reshape(acc_ref.shape)
-        m_ref[:, 0] = m_in_ref[...].reshape(-1)
-        l_ref[:, 0] = l_in_ref[...].reshape(-1)
-
-    # local (shard) token indices of this tile pair
-    jq = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
-    jk = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-    # global striped positions: shard r's local slot j is packed index j*n+r
-    gq = jq * n + q_shard
-    gk = jk * n + k_shard
-
-    def seg_ids(j, off_ref):
-        """Segment id per LOCAL index from the per-shard offsets."""
-
-        def body(b, acc):
-            return acc + jnp.where(j >= off_ref[b + 1], 1, 0)
-
-        return jax.lax.fori_loop(0, n_seqs, body, jnp.zeros_like(j))
-
-    seg_q = seg_ids(jq, qoff_ref)  # [block_q, 1]
-    seg_k = seg_ids(jk, koff_ref)  # [1, block_k]
-
-    # tile-level skip in GLOBAL coordinates: causal reach, segment-range
-    # overlap (per-shard seg ids stay monotone in the local index), window
-    run = (ik * block_k) * n + k_shard <= (iq * block_q + block_q - 1) * n + q_shard
-    run &= (seg_k[0, 0] <= seg_q[block_q - 1, 0]) & (
-        seg_q[0, 0] <= seg_k[0, block_k - 1]
-    )
-    if window is not None:
-        run &= (
-            (iq * block_q) * n + q_shard
-            - ((ik * block_k + block_k - 1) * n + k_shard)
-        ) < window
-
-    @pl.when(run)
-    def _update():
-        qpk = q_ref.shape[1]
-        qb = q_ref[...].astype(jnp.float32).reshape(block_q * qpk, -1)
-        kb = k_ref[:, 0, :].astype(jnp.float32)  # [block_k, D]
-        vb = v_ref[:, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [block_q * qpk, block_k]
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
-        mask = (seg_q == seg_k) & (gq >= gk)
-        if window is not None:
-            mask &= (gq - gk) < window
-        mask = jnp.broadcast_to(
-            mask[:, None, :], (block_q, qpk, block_k)
-        ).reshape(block_q * qpk, block_k)
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_ref[:, 0]
-        l_prev = l_ref[:, 0]
-        m_blk = jnp.max(s, axis=1)
-        m_new = jnp.maximum(m_prev, m_blk)
-        m_safe = jnp.maximum(m_new, -1e29)  # fully-masked-row guard
-        p = jnp.exp(s - m_safe[:, None])
-        p = jnp.where(mask, p, 0.0)
-        alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, jnp.exp(m_prev - m_safe))
-        l_new = alpha * l_prev + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:, 0] = jnp.where(m_blk <= NEG_INF / 2, m_prev, m_new)
-        l_ref[:, 0] = l_new
-
-    @pl.when(ik == n_k_blocks - 1)
-    def _emit():  # UNNORMALIZED: the carried state continues to the next step
-        o_ref[...] = acc_ref[...].reshape(o_ref.shape)
-        m_out_ref[...] = m_ref[:, 0].reshape(m_out_ref.shape)
-        l_out_ref[...] = l_ref[:, 0].reshape(l_out_ref.shape)
 
 
 def packed_flash_prefill_ring_chunk(
@@ -337,67 +315,8 @@ def packed_flash_prefill_ring_chunk(
     """One ring step: fold one striped KV chunk into the carried flash state
     with a single ragged launch.  Returns the updated (o, m, l) — finalize
     with ``o / l`` after the last step (empty rows keep m=-inf, l=0)."""
-    tl, h, d = q.shape
-    kvh = k.shape[1]
-    q_per_kv = h // kvh
-    block_q = min(block_q, tl)
-    block_k = min(block_k, tl)
-    while tl % block_q:  # 3/4-point buckets (e.g. 3*2^j): halve to a divisor
-        block_q //= 2
-    while tl % block_k:
-        block_k //= 2
-    assert block_q >= 1 and block_k >= 1, (tl, block_q, block_k)
-    n_seqs = int(q_offsets.shape[0]) - 1
-    nq, nk = tl // block_q, tl // block_k
-    scale = 1.0 / math.sqrt(d)
-    o_c, m_c, l_c = carry
-
-    kernel = functools.partial(
-        _ring_kernel, scale=scale, window=window, softcap=softcap,
-        q_shard=q_shard, k_shard=k_shard, n_shards=n_shards,
-        block_q=block_q, block_k=block_k, n_seqs=n_seqs, n_k_blocks=nk,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # q_offsets, k_offsets
-        grid=(kvh, nq, nk),
-        in_specs=[
-            pl.BlockSpec(
-                (block_q, q_per_kv, d), lambda g, iq, ik, qo, ko: (iq, g, 0)
-            ),
-            pl.BlockSpec((block_k, 1, d), lambda g, iq, ik, qo, ko: (ik, g, 0)),
-            pl.BlockSpec((block_k, 1, d), lambda g, iq, ik, qo, ko: (ik, g, 0)),
-            # carried flash state, blocked like q / its per-head stats
-            pl.BlockSpec(
-                (block_q, q_per_kv, d), lambda g, iq, ik, qo, ko: (iq, g, 0)
-            ),
-            pl.BlockSpec((block_q, q_per_kv), lambda g, iq, ik, qo, ko: (iq, g)),
-            pl.BlockSpec((block_q, q_per_kv), lambda g, iq, ik, qo, ko: (iq, g)),
-        ],
-        out_specs=[
-            pl.BlockSpec(
-                (block_q, q_per_kv, d), lambda g, iq, ik, qo, ko: (iq, g, 0)
-            ),
-            pl.BlockSpec((block_q, q_per_kv), lambda g, iq, ik, qo, ko: (iq, g)),
-            pl.BlockSpec((block_q, q_per_kv), lambda g, iq, ik, qo, ko: (iq, g)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q * q_per_kv, d), jnp.float32),
-            pltpu.VMEM((block_q * q_per_kv, 1), jnp.float32),
-            pltpu.VMEM((block_q * q_per_kv, 1), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((tl, h, d), jnp.float32),
-            jax.ShapeDtypeStruct((tl, h), jnp.float32),
-            jax.ShapeDtypeStruct((tl, h), jnp.float32),
-        ],
-        interpret=interpret,
-    )(
-        jnp.asarray(q_offsets, jnp.int32), jnp.asarray(k_offsets, jnp.int32),
-        q, k, v,
-        jnp.asarray(o_c, jnp.float32), jnp.asarray(m_c, jnp.float32),
-        jnp.asarray(l_c, jnp.float32),
+    return _call(
+        q, k, v, q_offsets, k_offsets, carry, q_shard=q_shard,
+        k_shard=k_shard, n_shards=n_shards, window=window, softcap=softcap,
+        block_q=block_q, block_k=block_k, interpret=interpret,
     )

@@ -84,19 +84,20 @@ _MIRROR_SCATTER = None
 
 def _mirror_scatter():
     """Lazily-jitted (K, V, slot_pos) mirror scatter, shared by the dirty
-    sync and the packed-prefill write-through.  Donation keeps it O(idx) and
-    allocation-free on accelerators; CPU falls back to a copy."""
+    sync and the packed-prefill write-through.  It donates the old mirror
+    on every backend (O(idx), allocation-free), so the old arrays are
+    deleted: nothing may keep a reference to a mirror across a sync — the
+    CPU tests see the same deletions the chip does."""
     global _MIRROR_SCATTER
     if _MIRROR_SCATTER is None:
         import jax
 
-        donate = (0, 1, 2) if jax.default_backend() != "cpu" else ()
         _MIRROR_SCATTER = jax.jit(
             lambda kd, vd, pd, idx, kn, vn, pn: (
                 kd.at[:, idx].set(kn), vd.at[:, idx].set(vn),
                 pd.at[idx].set(pn),
             ),
-            donate_argnums=donate,
+            donate_argnums=(0, 1, 2),
         )
     return _MIRROR_SCATTER
 

@@ -1,18 +1,24 @@
 """End-to-end serving driver (the paper's kind of system => serving driver).
 
 Runs the LoongServe engine over a synthetic workload, in `sim` mode (SIB
-clock; paper-scale) or `real` mode (reduced model actually generating tokens
-through the distributed pools).
+clock; paper-scale) or `real` mode (a model with random weights actually
+generating tokens through the distributed pools).  Real mode runs the toy
+float32 preset (`configs.reduced`, what the CPU tests use) unless
+``--widths published`` keeps the architecture's published widths and dtype,
+optionally cut to ``--layers`` whole layers.
 
   PYTHONPATH=src python -m repro.launch.serve --arch lwm-7b --dataset mixed \
       --rate 0.5 --n 64 --system loongserve
   PYTHONPATH=src python -m repro.launch.serve --real --n 8 --dataset sharegpt
+  PYTHONPATH=src python -m repro.launch.serve --real --widths published \
+      --layers 4 --instances 2 --capacity 8192 --page-size 16 --max-len 4096
 """
 from __future__ import annotations
 
 import argparse
 import json
 import sys
+from typing import Optional
 
 
 def build_engine(system: str, cfg, n_instances: int, capacity: int, **kw):
@@ -38,6 +44,34 @@ def build_engine(system: str, cfg, n_instances: int, capacity: int, **kw):
     raise ValueError(system)
 
 
+def real_model(arch: str, *, widths: str = "reduced",
+               n_layers: Optional[int] = None, dtype: Optional[str] = None,
+               seed: int = 0):
+    """(cfg, model, params) for real mode: random weights from ``seed``.
+    ``widths="reduced"`` is the toy float32 preset; ``"published"`` keeps
+    the config's widths and dtype.  ``n_layers`` cuts depth to that many
+    whole layers; ``dtype`` overrides the parameter dtype."""
+    import dataclasses
+
+    import jax
+
+    from repro.configs import get_config, reduced
+    from repro.models import build_model
+
+    cfg = get_config(arch)
+    if widths == "reduced":
+        cfg = reduced(cfg)
+    elif widths != "published":
+        raise ValueError(f"widths={widths!r}: expected reduced or published")
+    if n_layers is not None:
+        cfg = dataclasses.replace(cfg, n_layers=n_layers)
+    if dtype is not None:
+        cfg = dataclasses.replace(cfg, dtype=dtype)
+    model = build_model(cfg)
+    params = jax.jit(model.init)(jax.random.PRNGKey(seed))
+    return cfg, model, params
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="lwm-7b")
@@ -49,35 +83,44 @@ def main(argv=None) -> int:
     ap.add_argument("--rate", type=float, default=0.5)
     ap.add_argument("--n", type=int, default=64)
     ap.add_argument("--instances", type=int, default=8)
-    ap.add_argument("--capacity", type=int, default=250_000)
+    ap.add_argument("--capacity", type=int, default=None,
+                    help="KV slots per instance (sim: 250000, real: 4096)")
     ap.add_argument("--real", action="store_true",
-                    help="reduced model, real token generation on CPU")
+                    help="real token generation with random weights")
+    ap.add_argument("--widths", default="reduced",
+                    choices=["reduced", "published"],
+                    help="real mode: toy float32 preset or published widths")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="real mode: keep this many whole layers")
+    ap.add_argument("--page-size", type=int, default=1)
+    ap.add_argument("--max-len", type=int, default=256,
+                    help="real mode: prompt length cap")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args(argv)
 
-    from repro.configs import get_config, reduced
+    from repro.configs import get_config
     from repro.data import poisson_workload, with_prompts
 
-    cfg = get_config(args.arch)
-    kw = {}
+    kw = {"page_size": args.page_size}
     if args.real:
-        import jax
+        from repro.launch.compile_cache import enable_compile_cache
 
-        from repro.models import build_model
-
-        cfg = reduced(cfg)
-        model = build_model(cfg)
-        params = model.init(jax.random.PRNGKey(args.seed))
-        kw = dict(store_values=True, model=model, params=params)
-        capacity = 4096
+        enable_compile_cache()
+        cfg, model, params = real_model(
+            args.arch, widths=args.widths, n_layers=args.layers,
+            seed=args.seed,
+        )
+        kw.update(store_values=True, model=model, params=params)
+        capacity = args.capacity or 4096
         reqs = poisson_workload(args.dataset, args.n, args.rate,
-                                seed=args.seed, max_len=256)
+                                seed=args.seed, max_len=args.max_len)
         for r in reqs:
             r.max_new_tokens = min(r.max_new_tokens, 16)
         with_prompts(reqs, cfg.vocab_size, args.seed)
     else:
-        capacity = args.capacity
+        cfg = get_config(args.arch)
+        capacity = args.capacity or 250_000
         reqs = poisson_workload(args.dataset, args.n, args.rate, seed=args.seed)
 
     eng = build_engine(args.system, cfg, args.instances, capacity, **kw)

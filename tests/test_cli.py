@@ -44,3 +44,36 @@ def test_train_cli_grad_compression():
     out = _run(["repro.launch.train", "--arch", "lwm-7b", "--steps", "4",
                 "--batch", "2", "--seq", "48", "--grad-compression", "int8"])
     assert out.returncode == 0, out.stdout + out.stderr
+
+
+def test_compile_cache_honours_env(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache: JAX reads it
+    itself and the helper sets no other directory."""
+    import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    before = jax.config.jax_compilation_cache_dir
+    assert enable_compile_cache() == "/elsewhere/cache"
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_into_checkout(monkeypatch):
+    """Without the variable the cache sits at a fixed, gitignored path
+    inside the checkout."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = enable_compile_cache()
+        assert path == str(ROOT / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        cc.reset_cache()
+    assert ".jax_cache/" in (ROOT / ".gitignore").read_text().splitlines()

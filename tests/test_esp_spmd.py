@@ -40,7 +40,7 @@ import jax, jax.numpy as jnp
 from repro.core import ssm_sp
 from repro.models import ssm, xlstm
 from repro.configs import REGISTRY, reduced
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 key = jax.random.PRNGKey(0)
 cfg = reduced(REGISTRY["zamba2-2.7b"])
 p = ssm.init_mamba2(key, cfg.d_model, expand=cfg.ssm_expand, head_dim=cfg.ssm_head_dim,
@@ -78,7 +78,7 @@ from repro.core.esp import ESPAttnImpl
 from repro.core import striped
 from repro.models import attention as A
 from repro.configs import REGISTRY, reduced
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = reduced(REGISTRY["lwm-7b"], n_heads=4, n_kv_heads=4, d_head=16)
 impl = ESPAttnImpl(mesh, cfg, dop=2)  # two DoP-2 groups on the 4-wide axis
 B, S, H, D = 2, 64, 4, 16
@@ -115,7 +115,7 @@ def test_hlo_census_flops_exact():
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch.hlo import hlo_census
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 def f(x, w):
     def body(c, wl):
         h = c @ wl

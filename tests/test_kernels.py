@@ -158,3 +158,23 @@ def test_decode_partials_compose_to_full():
         np.asarray(combined, np.float32), np.asarray(ref_out, np.float32),
         atol=2e-5,
     )
+
+
+@pytest.mark.parametrize("env,want", [(None, None), ("interpret", "interpret")])
+def test_default_impl_follows_backend(monkeypatch, env, want):
+    """With no REPRO_KERNEL_IMPL the first dispatch picks the backend's
+    impl (Pallas on a TPU, XLA elsewhere); the variable overrides it."""
+    if env is None:
+        monkeypatch.delenv("REPRO_KERNEL_IMPL", raising=False)
+        want = "pallas" if jax.default_backend() == "tpu" else "xla"
+    else:
+        monkeypatch.setenv("REPRO_KERNEL_IMPL", env)
+    monkeypatch.setattr(ops, "_DEFAULT_IMPL", None)
+    assert ops.get_default_impl() == want
+
+
+def test_bad_impl_env_raises_at_first_dispatch(monkeypatch):
+    monkeypatch.setenv("REPRO_KERNEL_IMPL", "cuda")
+    monkeypatch.setattr(ops, "_DEFAULT_IMPL", None)
+    with pytest.raises(ValueError, match="REPRO_KERNEL_IMPL"):
+        ops.get_default_impl()
