@@ -36,6 +36,7 @@ from collections import OrderedDict
 from typing import Any, Dict, List, NamedTuple, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 class _USeg(NamedTuple):
@@ -221,7 +222,8 @@ class LocalExecutor:
             model, impl = self.eng.model, self._packed_prefill_impl
             arm = self._arm_packed_step
 
-            def step(params, tokens, positions, offsets, last_idx):
+            def prefill_packed_step(params, tokens, positions, offsets,
+                                    last_idx):
                 arm(impl, offsets, max_len_b, dop)
                 try:
                     return model.prefill_packed(
@@ -230,7 +232,7 @@ class LocalExecutor:
                 finally:
                     impl.end_step()
 
-            fn = self._program_put(key, jax.jit(step))
+            fn = self._program_put(key, jax.jit(prefill_packed_step))
         return fn
 
     def prefill_packed(self, batch) -> None:
@@ -241,7 +243,12 @@ class LocalExecutor:
         sampled from the packed logits, and the per-layer KV output is
         scattered straight into paged device storage at the slots the
         scheduler reserved (`pool.fill_packed` write-through — the decode
-        mirror never re-uploads prefill KV)."""
+        mirror never re-uploads prefill KV).
+
+        Host spans: ``loong.prefill`` around it all, and inside it
+        ``.pack`` (host arrays and their upload), ``.launch`` (the jitted
+        call, a compile included), ``.wait`` (the host blocked on the
+        logits), ``.sample`` and ``loong.kv.write``."""
         import jax.numpy as jnp
 
         eng = self.eng
@@ -255,89 +262,121 @@ class LocalExecutor:
         tb = self._token_bucket(-(-total // dop)) * dop
         bb = self._bucket(len(reqs), lo=1)
         max_len_b = self._bucket(max(lens))
-        tokens = np.zeros(tb, np.int32)
-        positions = np.zeros(tb, np.int32)
-        offsets = np.full(bb + 1, total, np.int32)
-        offsets[0] = 0
-        last_idx = np.zeros(bb, np.int32)
-        c = 0
-        for b, r in enumerate(reqs):
-            n = lens[b]
-            tokens[c : c + n] = np.asarray(r.prompt, np.int32)
-            positions[c : c + n] = np.arange(n)
-            c += n
-            offsets[b + 1] = c
-            last_idx[b] = c - 1
-        fn = self._packed_prefill_step(tb, bb, max_len_b, dop)
-        prev_impl = eng.model.attn_impl
-        eng.model.attn_impl = self._packed_prefill_impl
-        try:
-            logits, (k_packed, v_packed) = fn(
-                eng.params, jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(offsets), jnp.asarray(last_idx),
-            )
-        finally:
-            eng.model.attn_impl = prev_impl
-        logits = np.asarray(logits)
-        for b, r in enumerate(reqs):
-            row = self._guard_logits(r, logits[b])
-            if row is None:
-                continue  # quarantined: no first token, engine requeues
-            r.output_tokens.append(eng._sample_token(row))
-        if not eng.pool.pools[0].store_values:
-            return
-        # direct-to-pool paged KV writes: per instance, gather the packed
-        # columns this instance retains (striped placement from
-        # batch.placement — ESP scale-down stays zero-migration) and
-        # write-through into its mirror at the reserved block-table slots
-        # (per-data-shard mirrors under the mesh executor: the columns land
-        # on the instance's OWN device)
-        starts = np.concatenate([[0], np.cumsum(lens)])
-        per_inst: Dict[int, Tuple[List[np.ndarray], List[np.ndarray]]] = {}
-        for b, r in enumerate(reqs):
-            for inst, pos_list in batch.placement.get(r.rid, {}).items():
-                if not pos_list or inst in eng.failed:
-                    continue
-                p = np.asarray(pos_list, np.int64)
-                cols, slots = per_inst.setdefault(inst, ([], []))
-                cols.append(starts[b] + p)
-                slots.append(eng.pool.pools[inst].slots_for(r.rid, p))
+        with TraceAnnotation(
+            "loong.prefill", n_req=len(reqs), rid0=reqs[0].rid,
+            rid1=reqs[-1].rid, tokens=total, bucket=tb, dop=dop,
+        ):
+            with TraceAnnotation("loong.prefill.pack"):
+                tokens = np.zeros(tb, np.int32)
+                positions = np.zeros(tb, np.int32)
+                offsets = np.full(bb + 1, total, np.int32)
+                offsets[0] = 0
+                last_idx = np.zeros(bb, np.int32)
+                c = 0
+                for b, r in enumerate(reqs):
+                    n = lens[b]
+                    tokens[c : c + n] = np.asarray(r.prompt, np.int32)
+                    positions[c : c + n] = np.arange(n)
+                    c += n
+                    offsets[b + 1] = c
+                    last_idx[b] = c - 1
+                args = (jnp.asarray(tokens), jnp.asarray(positions),
+                        jnp.asarray(offsets), jnp.asarray(last_idx))
+            with TraceAnnotation("loong.prefill.launch"):
+                fn = self._packed_prefill_step(tb, bb, max_len_b, dop)
+                prev_impl = eng.model.attn_impl
+                eng.model.attn_impl = self._packed_prefill_impl
+                try:
+                    logits, (k_packed, v_packed) = fn(eng.params, *args)
+                finally:
+                    eng.model.attn_impl = prev_impl
+            with TraceAnnotation("loong.prefill.wait"):
+                logits = np.asarray(logits)
+            with TraceAnnotation("loong.prefill.sample"):
+                for b, r in enumerate(reqs):
+                    row = self._guard_logits(r, logits[b])
+                    if row is None:
+                        continue  # quarantined: no first token (requeued)
+                    r.output_tokens.append(eng._sample_token(row))
+            if not eng.pool.pools[0].store_values:
+                return
+            # direct-to-pool paged KV writes: per instance, gather the packed
+            # columns this instance retains (striped placement from
+            # batch.placement — ESP scale-down stays zero-migration) and
+            # write-through into its mirror at the reserved block-table slots
+            # (per-data-shard mirrors under the mesh executor: the columns
+            # land on the instance's OWN device)
+            with TraceAnnotation("loong.kv.write") as span:
+                starts = np.concatenate([[0], np.cumsum(lens)])
+                per_inst: Dict[int, Tuple[List[np.ndarray],
+                                          List[np.ndarray]]] = {}
+                for b, r in enumerate(reqs):
+                    placed = batch.placement.get(r.rid, {})
+                    for inst, pos_list in placed.items():
+                        if not pos_list or inst in eng.failed:
+                            continue
+                        p = np.asarray(pos_list, np.int64)
+                        cols, slots = per_inst.setdefault(inst, ([], []))
+                        cols.append(starts[b] + p)
+                        slots.append(eng.pool.pools[inst].slots_for(r.rid, p))
+                n = self._write_through(per_inst, k_packed, v_packed)
+                span.set_metadata(instances=len(per_inst), slots=n)
+
+    def _write_through(self, per_inst, k_packed, v_packed) -> int:
+        """Scatter each instance's packed KV columns into its pool mirror
+        (`KVPool.fill_packed`); ``per_inst`` maps an instance to (packed
+        column arrays, slot arrays).  Returns the slots written."""
+        import jax.numpy as jnp
+
+        n = 0
         for inst, (cols, slots) in per_inst.items():
             cidx = jnp.asarray(np.concatenate(cols))
-            eng.pool.pools[inst].fill_packed(
-                np.concatenate(slots),
+            slots = np.concatenate(slots)
+            n += len(slots)
+            self.eng.pool.pools[inst].fill_packed(
+                slots,
                 jnp.take(k_packed, cidx, axis=1),
                 jnp.take(v_packed, cidx, axis=1),
             )
+        return n
 
     def prefill_serial(self, batch) -> None:
-        """Per-request fallback (recurrent/hybrid state, moe capacity)."""
+        """Per-request fallback (recurrent/hybrid state, moe capacity), in
+        one ``loong.prefill`` span."""
         import jax.numpy as jnp
 
         from repro.kernels import ops
 
         eng = self.eng
-        for r in batch.requests:
-            # dispatch-counted so tests/benches can assert the packed paths
-            # (incl. DoP>1 ring fusion) never fall back to serial prefill
-            ops.dispatch_counts["prefill_serial_model"] += 1
-            toks = jnp.asarray(np.asarray(r.prompt, np.int32)[None])
-            logits, cache = eng.model.prefill(eng.params, {"tokens": toks})
-            row = self._guard_logits(r, np.asarray(logits[0, -1]))
-            if row is None:
-                continue  # quarantined: no first token, engine requeues
-            r.output_tokens.append(eng._sample_token(row))
-            if cache.k is not None:
-                k = np.asarray(cache.k[:, 0], np.float32)  # [L, T, KVH, D]
-                v = np.asarray(cache.v[:, 0], np.float32)
-                assign = batch.placement[r.rid]
-                for inst, positions in assign.items():
-                    if positions and inst not in eng.failed:
-                        eng.pool.pools[inst].fill(
-                            r.rid, positions, k[:, positions], v[:, positions]
-                        )
-            if cache.ssm is not None:
-                eng._real_cache[r.rid] = cache.ssm
+        reqs = batch.requests
+        with TraceAnnotation(
+            "loong.prefill", n_req=len(reqs), rid0=reqs[0].rid,
+            rid1=reqs[-1].rid, tokens=sum(r.input_len for r in reqs),
+            bucket=0, dop=batch.dop,
+        ):
+            for r in reqs:
+                # dispatch-counted so tests/benches can assert the packed
+                # paths (incl. DoP>1 ring fusion) never fall back to serial
+                # prefill
+                ops.dispatch_counts["prefill_serial_model"] += 1
+                toks = jnp.asarray(np.asarray(r.prompt, np.int32)[None])
+                logits, cache = eng.model.prefill(eng.params, {"tokens": toks})
+                row = self._guard_logits(r, np.asarray(logits[0, -1]))
+                if row is None:
+                    continue  # quarantined: no first token, engine requeues
+                r.output_tokens.append(eng._sample_token(row))
+                if cache.k is not None:
+                    k = np.asarray(cache.k[:, 0], np.float32)  # [L, T, KVH, D]
+                    v = np.asarray(cache.v[:, 0], np.float32)
+                    assign = batch.placement[r.rid]
+                    for inst, positions in assign.items():
+                        if positions and inst not in eng.failed:
+                            eng.pool.pools[inst].fill(
+                                r.rid, positions, k[:, positions],
+                                v[:, positions]
+                            )
+                if cache.ssm is not None:
+                    eng._real_cache[r.rid] = cache.ssm
 
     # -------------------------------------------------------------- decode
     def decode(self, g) -> None:
@@ -348,49 +387,62 @@ class LocalExecutor:
     def decode_paged(self, g) -> None:
         """Gather-free batched decode: ONE model step for the whole group;
         per layer, one paged-kernel launch per instance over the pool storage
-        in place (block tables), partials LSE-merged multi-master style."""
+        in place (block tables), partials LSE-merged multi-master style.
+
+        Host spans: ``loong.decode`` and inside it ``.pack``, ``.launch``,
+        ``.wait`` and ``.sample``, as for prefill."""
+        with TraceAnnotation(
+            "loong.decode", n_req=len(g.requests),
+            tokens=sum(r.seq_len - 1 for r in g.requests), dop=g.dop,
+        ):
+            self._decode_paged(g)
+
+    def _decode_paged(self, g) -> None:
         import jax.numpy as jnp
 
         from repro.core.paged_decode import PagedShard
         from repro.models.transformer import Cache
 
         eng = self.eng
-        rids = [r.rid for r in g.requests]
-        n_cached = np.array([r.seq_len - 1 for r in g.requests], np.int32)
-        shards, covered = [], np.zeros(len(rids), np.int64)
-        for pool in eng.pool.pools:
-            if pool.instance_id in eng.failed:
-                continue
-            table, lengths = pool.block_table(rids)
-            if not lengths.any():
-                continue
-            covered += lengths
-            # pool-owned incrementally-synced mirror: steady-state decode
-            # uploads one slot per request; packed-prefill slots upload 0
-            kdev, vdev, posdev = pool.device_paged_kv()
-            shards.append(PagedShard(
-                # block tables ride with the mirror's device so the whole
-                # per-shard partial computes where the stripe lives
-                k_pages=kdev,
-                v_pages=vdev,
-                table=pool._dev_put(table),
-                lengths=pool._dev_put(lengths),
-                # per-slot positions are only consumed by window masking
-                pos=(posdev if eng.cfg.sliding_window else None),
-            ))
-        # cache holds tokens 0..seq_len-2; the processed token's KV is
-        # produced by this step and appended at the master afterwards
-        assert (covered == n_cached).all(), (covered, n_cached)
-        toks = jnp.asarray([r.output_tokens[-1] for r in g.requests], jnp.int32)
-        cache = Cache(length=jnp.asarray(n_cached))
-        prev_impl = eng.model.attn_impl
-        eng.model.attn_impl = self._paged_impl
-        self._paged_impl.begin_step(shards)
-        try:
-            logits, _, kvs = eng.model.decode(eng.params, toks, cache)
-        finally:
-            self._paged_impl.end_step()
-            eng.model.attn_impl = prev_impl
+        with TraceAnnotation("loong.decode.pack"):
+            rids = [r.rid for r in g.requests]
+            n_cached = np.array([r.seq_len - 1 for r in g.requests], np.int32)
+            shards, covered = [], np.zeros(len(rids), np.int64)
+            for pool in eng.pool.pools:
+                if pool.instance_id in eng.failed:
+                    continue
+                table, lengths = pool.block_table(rids)
+                if not lengths.any():
+                    continue
+                covered += lengths
+                # pool-owned incrementally-synced mirror: steady-state decode
+                # uploads one slot per request; packed-prefill slots upload 0
+                kdev, vdev, posdev = pool.device_paged_kv()
+                shards.append(PagedShard(
+                    # block tables ride with the mirror's device so the whole
+                    # per-shard partial computes where the stripe lives
+                    k_pages=kdev,
+                    v_pages=vdev,
+                    table=pool._dev_put(table),
+                    lengths=pool._dev_put(lengths),
+                    # per-slot positions are only consumed by window masking
+                    pos=(posdev if eng.cfg.sliding_window else None),
+                ))
+            # cache holds tokens 0..seq_len-2; the processed token's KV is
+            # produced by this step and appended at the master afterwards
+            assert (covered == n_cached).all(), (covered, n_cached)
+            toks = jnp.asarray([r.output_tokens[-1] for r in g.requests],
+                               jnp.int32)
+            cache = Cache(length=jnp.asarray(n_cached))
+        with TraceAnnotation("loong.decode.launch"):
+            prev_impl = eng.model.attn_impl
+            eng.model.attn_impl = self._paged_impl
+            self._paged_impl.begin_step(shards)
+            try:
+                logits, _, kvs = eng.model.decode(eng.params, toks, cache)
+            finally:
+                self._paged_impl.end_step()
+                eng.model.attn_impl = prev_impl
         self._emit_decoded(g, logits, kvs)
 
     def _emit_decoded(self, g, logits, kvs) -> None:
@@ -399,13 +451,18 @@ class LocalExecutor:
         slot is allocated.  logits [>=B, V]; kvs [L, >=B, 1, KVH, D] (rows
         past len(g.requests) are bucket padding)."""
         eng = self.eng
-        logits = np.asarray(logits)
-        for b, r in enumerate(g.requests):
-            row = self._guard_logits(r, logits[b])
-            if row is None:
-                continue  # quarantined: no token, no KV stash
-            r.output_tokens.append(eng._sample_token(row))
-            if kvs is not None:
+        with TraceAnnotation("loong.decode.wait"):
+            logits = np.asarray(logits)
+        with TraceAnnotation("loong.decode.sample"):
+            emitted = []
+            for b, r in enumerate(g.requests):
+                row = self._guard_logits(r, logits[b])
+                if row is None:
+                    continue  # quarantined: no token, no KV stash
+                r.output_tokens.append(eng._sample_token(row))
+                emitted.append((b, r))
+        if kvs is not None:
+            for b, r in emitted:
                 eng._pending_kv[r.rid] = (
                     np.asarray(kvs[0][:, b], np.float32),  # [L, 1, KVH, D]
                     np.asarray(kvs[1][:, b], np.float32),
@@ -584,7 +641,8 @@ class LocalExecutor:
 
             model, impl = self.eng.model, self._unified_impl
 
-            def step(params, tokens, positions, offsets, last_idx, shards):
+            def unified_step(params, tokens, positions, offsets, last_idx,
+                             shards):
                 impl.begin_step(
                     offsets, positions, max_seq_len=max_len_b, shards=shards
                 )
@@ -596,8 +654,16 @@ class LocalExecutor:
                 finally:
                     impl.end_step()
 
-            fn = self._program_put(key, jax.jit(step))
+            fn = self._program_put(key, jax.jit(unified_step))
         return fn
+
+    def _unified_span(self, work, segs) -> TraceAnnotation:
+        """The ``loong.unified`` span of one iteration."""
+        return TraceAnnotation(
+            "loong.unified", n_req=len(segs), rid0=segs[0].r.rid,
+            rid1=segs[-1].r.rid, tokens=sum(s.ln for s in segs),
+            dop=len(work.alive_instances(self.eng.failed)),
+        )
 
     def unified(self, work) -> None:
         """ONE packed model step for a whole unified iteration: a bounded
@@ -606,34 +672,38 @@ class LocalExecutor:
         attention folds on top of the paged prefix partials
         (`core.unified`).  First/next tokens are sampled from the packed
         logits, prefill chunk KV write-throughs at the reserved slots, and
-        decode KV is stashed exactly like `decode_paged`."""
+        decode KV is stashed exactly like `decode_paged`.  Host spans:
+        ``loong.unified`` with ``.pack``, ``.launch``, ``.wait``, ``.sample``
+        and ``loong.kv.write``."""
         segs = self._unified_segments(work)
-        self._unified_local(work, segs)
+        with self._unified_span(work, segs):
+            self._unified_local(work, segs)
 
     def _unified_local(self, work, segs) -> None:
         import jax.numpy as jnp
 
         eng = self.eng
-        tokens, positions, offsets, last_idx = self._unified_pack(segs)
-        tb, bb = len(tokens), len(last_idx)
-        max_len_b = self._bucket(max(s.ln for s in segs))
-        shards, covered = self._unified_shards(segs, tb)
-        limits = np.array([s.limit for s in segs], np.int64)
-        assert (covered == limits).all(), (covered, limits)
+        with TraceAnnotation("loong.unified.pack"):
+            tokens, positions, offsets, last_idx = self._unified_pack(segs)
+            tb, bb = len(tokens), len(last_idx)
+            max_len_b = self._bucket(max(s.ln for s in segs))
+            shards, covered = self._unified_shards(segs, tb)
+            limits = np.array([s.limit for s in segs], np.int64)
+            assert (covered == limits).all(), (covered, limits)
+            args = (jnp.asarray(tokens), jnp.asarray(positions),
+                    jnp.asarray(offsets), jnp.asarray(last_idx), tuple(shards))
         self._unified_count(segs)
-        fn = self._unified_step(tb, bb, max_len_b, len(shards))
-        prev_impl = eng.model.attn_impl
-        eng.model.attn_impl = self._unified_impl
-        try:
-            logits, (k_packed, v_packed) = fn(
-                eng.params, jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(offsets), jnp.asarray(last_idx), tuple(shards),
-            )
-        finally:
-            eng.model.attn_impl = prev_impl
-        self._unified_emit(
-            work, segs, np.asarray(logits), None, k_packed, v_packed, None
-        )
+        with TraceAnnotation("loong.unified.launch"):
+            fn = self._unified_step(tb, bb, max_len_b, len(shards))
+            prev_impl = eng.model.attn_impl
+            eng.model.attn_impl = self._unified_impl
+            try:
+                logits, (k_packed, v_packed) = fn(eng.params, *args)
+            finally:
+                eng.model.attn_impl = prev_impl
+        with TraceAnnotation("loong.unified.wait"):
+            logits = np.asarray(logits)
+        self._unified_emit(work, segs, logits, None, k_packed, v_packed, None)
 
     def _unified_emit(
         self, work, segs, logits, ids, k_packed, v_packed, colmap
@@ -653,46 +723,46 @@ class LocalExecutor:
         starts = np.concatenate([[0], np.cumsum([s.ln for s in segs])])
         col_of = (lambda c: c) if colmap is None else (lambda c: colmap[c])
         emitted = set()
-        for b, s in enumerate(segs):
-            if not s.final:
-                continue
-            if ids is None:
-                row = self._guard_logits(s.r, logits[b])
-                if row is None:
-                    continue  # quarantined: no token, engine requeues
-                s.r.output_tokens.append(eng._sample_token(row))
-            else:
-                s.r.output_tokens.append(int(ids[b]))
-            emitted.add(s.r.rid)
+        with TraceAnnotation("loong.unified.sample"):
+            for b, s in enumerate(segs):
+                if not s.final:
+                    continue
+                if ids is None:
+                    row = self._guard_logits(s.r, logits[b])
+                    if row is None:
+                        continue  # quarantined: no token, engine requeues
+                    s.r.output_tokens.append(eng._sample_token(row))
+                else:
+                    s.r.output_tokens.append(int(ids[b]))
+                emitted.add(s.r.rid)
         if not eng.pool.pools[0].store_values:
             return
-        per_inst: Dict[int, Tuple[List[np.ndarray], List[np.ndarray]]] = {}
         dec_cols: List[int] = []
         dec_reqs: List[Any] = []
-        for b, s in enumerate(segs):
-            if s.decode:
-                if s.r.rid in emitted:  # quarantined rows stash no KV
-                    dec_cols.append(int(col_of(starts[b])))
-                    dec_reqs.append(s.r)
-                continue
-            lo, hi = s.start, s.start + s.ln
-            for inst, pos_list in work.batch.placement.get(s.r.rid, {}).items():
-                if not pos_list or inst in eng.failed:
+        with TraceAnnotation("loong.kv.write") as span:
+            per_inst: Dict[int, Tuple[List[np.ndarray],
+                                      List[np.ndarray]]] = {}
+            for b, s in enumerate(segs):
+                if s.decode:
+                    if s.r.rid in emitted:  # quarantined rows stash no KV
+                        dec_cols.append(int(col_of(starts[b])))
+                        dec_reqs.append(s.r)
                     continue
-                p = np.asarray(pos_list, np.int64)
-                p = p[(p >= lo) & (p < hi)]
-                if not len(p):
-                    continue
-                cols, slots = per_inst.setdefault(inst, ([], []))
-                cols.append(np.asarray(col_of(starts[b] + (p - lo)), np.int64))
-                slots.append(eng.pool.pools[inst].slots_for(s.r.rid, p))
-        for inst, (cols, slots) in per_inst.items():
-            cidx = jnp.asarray(np.concatenate(cols))
-            eng.pool.pools[inst].fill_packed(
-                np.concatenate(slots),
-                jnp.take(k_packed, cidx, axis=1),
-                jnp.take(v_packed, cidx, axis=1),
-            )
+                lo, hi = s.start, s.start + s.ln
+                placed = work.batch.placement.get(s.r.rid, {})
+                for inst, pos_list in placed.items():
+                    if not pos_list or inst in eng.failed:
+                        continue
+                    p = np.asarray(pos_list, np.int64)
+                    p = p[(p >= lo) & (p < hi)]
+                    if not len(p):
+                        continue
+                    cols, slots = per_inst.setdefault(inst, ([], []))
+                    cols.append(
+                        np.asarray(col_of(starts[b] + (p - lo)), np.int64))
+                    slots.append(eng.pool.pools[inst].slots_for(s.r.rid, p))
+            n = self._write_through(per_inst, k_packed, v_packed)
+            span.set_metadata(instances=len(per_inst), slots=n)
         if dec_cols:
             dc = jnp.asarray(np.asarray(dec_cols, np.int64))
             kd = np.asarray(jnp.take(k_packed, dc, axis=1), np.float32)
@@ -911,16 +981,17 @@ class MeshExecutor(LocalExecutor):
             if rb is not None:
                 from repro.core.esp import paged_decode_iteration_spmd
 
-                def step(params, toks, n_cached, k_g, v_g, tbl_g, len_g,
-                         pos_g, route):
+                def decode_routed_spmd_step(params, toks, n_cached, k_g, v_g,
+                                            tbl_g, len_g, pos_g, route):
                     return paged_decode_iteration_spmd(
                         mesh, model, impl, params, toks, n_cached,
                         k_g, v_g, tbl_g, len_g, pos_g, route,
                         overlap=overlap,
                     )
+                step = decode_routed_spmd_step
             else:
-                def step(params, toks, n_cached, k_g, v_g, tbl_g, len_g,
-                         pos_g):
+                def decode_spmd_step(params, toks, n_cached, k_g, v_g, tbl_g,
+                                     len_g, pos_g):
                     shards = SpmdPagedShards(k_g, v_g, tbl_g, len_g, pos_g)
                     impl.begin_step(shards, mesh=mesh, overlap=overlap)
                     try:
@@ -930,6 +1001,7 @@ class MeshExecutor(LocalExecutor):
                     finally:
                         impl.end_step()
                     return logits, kvs
+                step = decode_spmd_step
 
             fn = self._program_put(key, jax.jit(step))
         return fn
@@ -1035,26 +1107,28 @@ class MeshExecutor(LocalExecutor):
             args.append(jax.device_put(route, sh))
         return fn, tuple(args), rowmap
 
-    def decode_paged(self, g) -> None:
+    def _decode_paged(self, g) -> None:
         """One shard_map decode iteration for the whole group: per layer,
         each rank's paged partial computes over the mirror it holds and the
         LSE-merge is a collective XLA can schedule against independent
         compute — zero per-shard Python-loop merges, zero per-layer
         `device_put` hops (see `core.esp.paged_decode_spmd`)."""
-        setup = self._decode_spmd_setup(g) if self.spmd_decode else None
+        with TraceAnnotation("loong.decode.pack"):
+            setup = self._decode_spmd_setup(g) if self.spmd_decode else None
         if setup is None:
-            return super().decode_paged(g)
+            return super()._decode_paged(g)
         fn, args, rowmap = setup
         eng = self.eng
-        prev_impl = eng.model.attn_impl
-        eng.model.attn_impl = self._paged_impl
-        try:
-            if rowmap is None:
-                logits, kvs = fn(*args)
-            else:
-                toks_next, k_rt, v_rt = fn(*args)
-        finally:
-            eng.model.attn_impl = prev_impl
+        with TraceAnnotation("loong.decode.launch"):
+            prev_impl = eng.model.attn_impl
+            eng.model.attn_impl = self._paged_impl
+            try:
+                if rowmap is None:
+                    logits, kvs = fn(*args)
+                else:
+                    toks_next, k_rt, v_rt = fn(*args)
+            finally:
+                eng.model.attn_impl = prev_impl
         if rowmap is None:
             self._emit_decoded(g, logits, kvs)
         else:
@@ -1072,13 +1146,15 @@ class MeshExecutor(LocalExecutor):
         targets the host-sampling paths (`_emit_decoded`/serial/packed);
         `_logit_poison` entries are simply not consumed on this path."""
         eng = self.eng
-        toks = np.asarray(toks_next)
-        k_rt = np.asarray(k_rt, np.float32)
-        v_rt = np.asarray(v_rt, np.float32)
-        for b, r in enumerate(g.requests):
-            r.output_tokens.append(int(toks[b]))
-            row = rowmap[r.rid]
-            eng._pending_kv[r.rid] = (k_rt[:, row], v_rt[:, row])
+        with TraceAnnotation("loong.decode.wait"):
+            toks = np.asarray(toks_next)
+            k_rt = np.asarray(k_rt, np.float32)
+            v_rt = np.asarray(v_rt, np.float32)
+        with TraceAnnotation("loong.decode.sample"):
+            for b, r in enumerate(g.requests):
+                r.output_tokens.append(int(toks[b]))
+                row = rowmap[r.rid]
+                eng._pending_kv[r.rid] = (k_rt[:, row], v_rt[:, row])
 
     # unified: the whole fused iteration as ONE shard_map program ---------
     def _unified_spmd_program(self, tb, bb, max_len_b, mesh):
@@ -1095,15 +1171,15 @@ class MeshExecutor(LocalExecutor):
             model, impl = self.eng.model, self._unified_impl
             dbuf = self.double_buffer
 
-            def step(params, toks, positions, offsets, last_idx, k_g, v_g,
-                     tbl_g, len_g, pos_g):
+            def unified_spmd_step(params, toks, positions, offsets, last_idx,
+                                  k_g, v_g, tbl_g, len_g, pos_g):
                 return unified_iteration_spmd(
                     mesh, model, impl, params, toks, positions, offsets,
                     last_idx, k_g, v_g, tbl_g, len_g, pos_g,
                     max_seq_len=max_len_b, double_buffer=dbuf,
                 )
 
-            fn = self._program_put(key, jax.jit(step))
+            fn = self._program_put(key, jax.jit(unified_spmd_step))
         return fn
 
     def _unified_spmd_setup(self, work, segs):
@@ -1196,20 +1272,24 @@ class MeshExecutor(LocalExecutor):
         in-program.  Falls back to the in-process fused loop when the group
         cannot run SPMD."""
         segs = self._unified_segments(work)
-        setup = (
-            self._unified_spmd_setup(work, segs) if self.spmd_decode else None
-        )
-        if setup is None:
-            return self._unified_local(work, segs)
-        fn, args, inv = setup
-        self._unified_count(segs)
-        eng = self.eng
-        prev_impl = eng.model.attn_impl
-        eng.model.attn_impl = self._unified_impl
-        try:
-            ids, k_packed, v_packed = fn(*args)
-        finally:
-            eng.model.attn_impl = prev_impl
-        self._unified_emit(
-            work, segs, None, np.asarray(ids), k_packed, v_packed, inv
-        )
+        with self._unified_span(work, segs):
+            with TraceAnnotation("loong.unified.pack"):
+                setup = (
+                    self._unified_spmd_setup(work, segs)
+                    if self.spmd_decode else None
+                )
+            if setup is None:
+                return self._unified_local(work, segs)
+            fn, args, inv = setup
+            self._unified_count(segs)
+            eng = self.eng
+            with TraceAnnotation("loong.unified.launch"):
+                prev_impl = eng.model.attn_impl
+                eng.model.attn_impl = self._unified_impl
+                try:
+                    ids, k_packed, v_packed = fn(*args)
+                finally:
+                    eng.model.attn_impl = prev_impl
+            with TraceAnnotation("loong.unified.wait"):
+                ids = np.asarray(ids)
+            self._unified_emit(work, segs, None, ids, k_packed, v_packed, inv)

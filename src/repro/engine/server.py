@@ -53,7 +53,6 @@ class EngineMetrics:
     rejected: int = 0
     scaling_migration_bytes: int = 0  # ESP transitions: MUST stay 0
     reactive_migration_bytes: int = 0
-    q_broadcast_bytes: int = 0
     prefill_iters: int = 0
     decode_iters: int = 0
     # degradation-path counters (observability for planner/pool divergence
@@ -95,9 +94,6 @@ class EngineMetrics:
                 if vals:
                     out[f"{name}_mean"] = float(np.mean(vals))
                     out[f"{name}_p90"] = float(np.percentile(vals, 90))
-            span = max(r.finish_time for r in fin) - min(r.arrival for r in fin)
-            toks = sum(r.seq_len for r in fin)
-            out["throughput_tok_s"] = toks / max(span, 1e-9)
         return out
 
     def snapshot(self) -> Dict[str, float]:
@@ -567,6 +563,17 @@ class LoongServeEngine(BaseServingEngine):
         return free < self.admission_watermark * total
 
     def _try_schedule(self) -> None:
+        """One scheduling pass, in a ``loong.schedule`` span that records
+        the requests pending and the completion events it launched."""
+        from jax.profiler import TraceAnnotation
+
+        queued = len(self.events)
+        with TraceAnnotation("loong.schedule",
+                             pending=len(self.pending)) as span:
+            self._schedule_rounds()
+            span.set_metadata(launched=len(self.events) - queued)
+
+    def _schedule_rounds(self) -> None:
         for _ in range(4):  # drain: admit more work onto leftover instances
             idle = [
                 i
@@ -723,11 +730,6 @@ class LoongServeEngine(BaseServingEngine):
             self._occupy(g.instances, end)
             for r in g.requests:
                 r.decode_exec_time += dur
-            # q-broadcast volume (multi-master): q + partial returns
-            self.metrics.q_broadcast_bytes += (
-                2 * len(g.requests) * self.cfg.n_heads * self.cfg.head_dim
-                * 2 * max(g.dop - 1, 0)
-            )
             self.metrics.decode_iters += 1
             self._running_decode_ends[id(g)] = end
             # launch-time sequence stamp: decode_done uses it to tell "still

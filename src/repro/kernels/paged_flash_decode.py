@@ -205,6 +205,8 @@ def paged_flash_decode_partial(
             jax.ShapeDtypeStruct((b, h, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="paged_decode_attn",
+        metadata={"kernel": "paged_decode_attn"},
     )(
         jnp.asarray(block_table, jnp.int32),
         jnp.asarray(lengths, jnp.int32),

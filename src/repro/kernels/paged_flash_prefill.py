@@ -221,9 +221,11 @@ def _kernel(*refs, scale: float, window: Optional[int],
             o_ref[:, cols] = acc_ref[:, cols] / jnp.where(l_h == 0.0, 1.0, l_h)
 
 
-def _call(q, k, v, q_offsets, k_offsets, carry, *, q_shard, k_shard,
+def _call(q, k, v, q_offsets, k_offsets, carry, *, name, q_shard, k_shard,
           n_shards, window, softcap, block_q, block_k, interpret):
-    """Shared pallas_call for both entry points (see `_kernel`)."""
+    """Shared pallas_call for both entry points (see `_kernel`); ``name``
+    is the kernel's name in the compiled program and the profiler's trace
+    (the custom call's ``kernel_metadata``)."""
     t, h, d = q.shape
     kvh = k.shape[1]
     q_per_kv = h // kvh
@@ -266,6 +268,7 @@ def _call(q, k, v, q_offsets, k_offsets, carry, *, q_shard, k_shard,
     )
     outs = pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interpret,
+        name=name, metadata={"kernel": name},
     )(jnp.asarray(q_offsets, jnp.int32), jnp.asarray(k_offsets, jnp.int32),
       *operands)
     return (outs[0].reshape(t, h, d),) + tuple(outs[1:])
@@ -286,7 +289,8 @@ def packed_flash_prefill(
     """One ragged batched launch over the packed token axis; returns the
     normalized attention output [T, H, D] (f32)."""
     return _call(
-        q, k, v, seq_offsets, seq_offsets, None, q_shard=0, k_shard=0,
+        q, k, v, seq_offsets, seq_offsets, None, name="prefill_packed_attn",
+        q_shard=0, k_shard=0,
         n_shards=1, window=window, softcap=softcap, block_q=block_q,
         block_k=block_k, interpret=interpret,
     )[0]
@@ -316,7 +320,8 @@ def packed_flash_prefill_ring_chunk(
     with a single ragged launch.  Returns the updated (o, m, l) — finalize
     with ``o / l`` after the last step (empty rows keep m=-inf, l=0)."""
     return _call(
-        q, k, v, q_offsets, k_offsets, carry, q_shard=q_shard,
+        q, k, v, q_offsets, k_offsets, carry, name="prefill_ring_chunk_attn",
+        q_shard=q_shard,
         k_shard=k_shard, n_shards=n_shards, window=window, softcap=softcap,
         block_q=block_q, block_k=block_k, interpret=interpret,
     )

@@ -92,13 +92,11 @@ def _mirror_scatter():
     if _MIRROR_SCATTER is None:
         import jax
 
-        _MIRROR_SCATTER = jax.jit(
-            lambda kd, vd, pd, idx, kn, vn, pn: (
-                kd.at[:, idx].set(kn), vd.at[:, idx].set(vn),
-                pd.at[idx].set(pn),
-            ),
-            donate_argnums=(0, 1, 2),
-        )
+        def kv_mirror_scatter(kd, vd, pd, idx, kn, vn, pn):
+            return (kd.at[:, idx].set(kn), vd.at[:, idx].set(vn),
+                    pd.at[idx].set(pn))
+
+        _MIRROR_SCATTER = jax.jit(kv_mirror_scatter, donate_argnums=(0, 1, 2))
     return _MIRROR_SCATTER
 
 
@@ -405,14 +403,18 @@ class KVPool:
     def _sync_host(self) -> None:
         """On-demand download of stale slots from the mirror to the host
         management copy (migration / gather / SWA compaction / checkpoints
-        read it).  Off the prefill critical path by construction."""
+        read it).  Off the prefill critical path by construction.  A
+        download runs in a ``loong.kv.host_sync`` span."""
         if self._stale_count == 0:
             return
         slots = np.nonzero(self._stale_host)[0]
         if self._mirror is not None:
-            kd, vd, _ = self._mirror
-            self.k[:, slots] = np.asarray(kd[:, slots], np.float32)
-            self.v[:, slots] = np.asarray(vd[:, slots], np.float32)
+            from jax.profiler import TraceAnnotation
+
+            with TraceAnnotation("loong.kv.host_sync", slots=len(slots)):
+                kd, vd, _ = self._mirror
+                self.k[:, slots] = np.asarray(kd[:, slots], np.float32)
+                self.v[:, slots] = np.asarray(vd[:, slots], np.float32)
             self.host_syncs += 1
         self._stale_host[:] = False
         self._stale_count = 0
@@ -504,30 +506,36 @@ class KVPool:
         storage.  Steady-state decode uploads only the slots written since
         the last call (one per request per iteration), not the pool; slots
         landed through `fill_packed` were written device-side already and
-        upload nothing."""
-        import jax.numpy as jnp
-
+        upload nothing.  An upload runs in a ``loong.kv.upload`` span."""
         assert self.store_values, "device mirror needs value storage"
         full, dirty = self.consume_dirty()
         cur = self._mirror
         if cur is None or full:
-            # a full resync uploads the HOST copy wholesale: pull any
-            # stale-host slots (authoritative only in the mirror) down first
-            # or their KV would be overwritten with never-synced host data
-            self._sync_host()
-            cur = (self._dev_put(self.k), self._dev_put(self.v),
-                   self._dev_put(self.slot_pos))
+            from jax.profiler import TraceAnnotation
+
+            with TraceAnnotation("loong.kv.upload", slots=self.capacity):
+                # a full resync uploads the HOST copy wholesale: pull any
+                # stale-host slots (authoritative only in the mirror) down
+                # first or their KV would be overwritten with never-synced
+                # host data
+                self._sync_host()
+                cur = (self._dev_put(self.k), self._dev_put(self.v),
+                       self._dev_put(self.slot_pos))
             self.mirror_full_syncs += 1
             self.mirror_uploaded_slots += self.capacity
         elif len(dirty):
+            from jax.profiler import TraceAnnotation
+
             n = len(dirty)
-            bucket = _pad_bucket(n)
-            idx = np.concatenate([dirty, np.full(bucket - n, dirty[-1])])
-            cur = _mirror_scatter()(
-                cur[0], cur[1], cur[2], self._dev_put(idx),
-                self._dev_put(self.k[:, idx]), self._dev_put(self.v[:, idx]),
-                self._dev_put(self.slot_pos[idx]),
-            )
+            with TraceAnnotation("loong.kv.upload", slots=n):
+                bucket = _pad_bucket(n)
+                idx = np.concatenate([dirty, np.full(bucket - n, dirty[-1])])
+                cur = _mirror_scatter()(
+                    cur[0], cur[1], cur[2], self._dev_put(idx),
+                    self._dev_put(self.k[:, idx]),
+                    self._dev_put(self.v[:, idx]),
+                    self._dev_put(self.slot_pos[idx]),
+                )
             self.mirror_uploaded_slots += n
         self._mirror = cur
         return cur

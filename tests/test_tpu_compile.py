@@ -186,3 +186,40 @@ def test_switched_paged_partial_compiles_on_mesh(mesh4, no_persistent_cache):
         _spec(sh, (n, b, max_pages), jnp.int32),
         _spec(sh, (n, b), jnp.int32),
     )
+
+
+def _kernel_fn(kernel, s):
+    """(function, argument specs) running one main-path kernel at small
+    shapes."""
+    from repro.kernels import ops
+
+    t = 256
+    if kernel == "paged_decode_attn":
+        pages = s((64, 16, KVH, D), jnp.float32)
+        return (lambda q, kp, vp, table, lengths: ops.paged_decode_partial(
+            q, kp, vp, table, lengths, impl="pallas").o,
+            (s((2, 1, H, D), jnp.bfloat16), pages, pages,
+             s((2, 8), jnp.int32), s((2,), jnp.int32)))
+    q, kv = s((t, H, D), jnp.bfloat16), s((t, KVH, D), jnp.bfloat16)
+    off = s((3,), jnp.int32)
+    if kernel == "prefill_packed_attn":
+        return (lambda q, k, v, o: ops.prefill_packed(q, k, v, o,
+                                                      impl="pallas"),
+                (q, kv, kv, off))
+    state = (s((t, H, D), jnp.float32), s((t, H), jnp.float32),
+             s((t, H), jnp.float32))
+    return (lambda q, k, v, qo, ko, o, m, l: ops.prefill_ring_chunk(
+        q, k, v, qo, ko, (o, m, l), q_shard=1, k_shard=0, n_shards=2,
+        impl="pallas"), (q, kv, kv, off, off) + state)
+
+
+@pytest.mark.parametrize("kernel", ["paged_decode_attn", "prefill_packed_attn",
+                                    "prefill_ring_chunk_attn"])
+def test_kernel_carries_its_name(one_chip, no_persistent_cache, kernel):
+    """Each main-path kernel's custom call carries its name in the
+    ``kernel_metadata`` attribute, which the profiler prints into the
+    device op's name."""
+    fn, args = _kernel_fn(kernel, lambda shape, dt: _spec(one_chip, shape, dt))
+    text = _compile(fn, *args).as_text()
+    i = text.index("kernel_metadata=")
+    assert f'"kernel":"{kernel}"' in text[i:i + 80], text[i:i + 80]
