@@ -65,8 +65,8 @@ def ring_packed_prefill(
     softcap: Optional[float] = None,
     max_seq_len: Optional[int] = None,
     impl: Optional[str] = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
 ):
     """Ring-fused packed ragged prefill for one DoP>1 ESP group (single-
     process simulation of the striped ppermute ring).
@@ -128,8 +128,8 @@ def switched_ring_chunk(
     softcap: Optional[float] = None,
     max_seq_len: Optional[int] = None,
     impl: Optional[str] = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
 ):
     """One ring-chunk fold inside a shard_map body, dispatching the
     CONFIGURED kernel impl instead of forcing the banded XLA fallback.
@@ -192,8 +192,8 @@ def ring_packed_prefill_spmd(
     softcap: Optional[float] = None,
     max_seq_len: Optional[int] = None,
     impl: Optional[str] = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     double_buffer: bool = True,
 ):
     """Mesh-native ring-fused packed ragged prefill: ONE shard_map program

@@ -75,8 +75,8 @@ def unified_chunk_attention(
     window: Optional[int] = None,
     softcap: Optional[float] = None,
     impl: Optional[str] = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
 ):
     """One layer of unified attention, single-process form.
 
@@ -140,8 +140,8 @@ class UnifiedAttnImpl(DefaultAttnImpl):
         axis_name: Optional[str] = None,
         n_ranks: int = 1,
         double_buffer: bool = True,
-        block_q: int = 128,
-        block_k: int = 128,
+        block_q: Optional[int] = None,
+        block_k: Optional[int] = None,
     ) -> None:
         """Arm one step.  ``positions`` is the FULL packed-axis position
         vector ([T]; striped order in axis mode) — the per-token query_pos of

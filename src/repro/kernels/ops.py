@@ -151,19 +151,23 @@ def decode_partial(
 
 def prefill_packed(
     q, k, v, seq_offsets, *, window=None, softcap=None, max_seq_len=None,
-    impl: Optional[str] = None, block_q: int = 128, block_k: int = 128,
+    impl: Optional[str] = None, block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
 ):
     """Packed ragged causal prefill: ONE launch for a whole prefill batch
     concatenated on a single token axis (see kernels/paged_flash_prefill.py).
     ``max_seq_len`` (static) bounds the banded XLA fallback's reach; the
-    Pallas kernel skips non-interacting tiles from the prefetched offsets."""
+    Pallas kernel skips non-interacting tiles from the prefetched offsets.
+    ``block_q``/``block_k`` ``None``: the kernel chooses its tiles from the
+    shapes (`choose_tiles`); the banded fallback's q block is ``block_q``
+    or `ref.BAND`."""
     impl = impl or get_default_impl()
     check_fault("prefill_packed")
     dispatch_counts["prefill_packed"] += 1
     if impl == "xla":
         return ref.packed_prefill_banded(
             q, k, v, seq_offsets, window=window, softcap=softcap,
-            block_q=block_q, max_seq_len=max_seq_len,
+            block_q=block_q or ref.BAND, max_seq_len=max_seq_len,
         )
     return _pfp_kernel(
         q, k, v, jnp.asarray(seq_offsets, jnp.int32), window=window,
@@ -176,7 +180,8 @@ def prefill_ring_chunk(
     q, k, v, q_offsets, k_offsets, carry=None, *,
     q_shard: int, k_shard: int, n_shards: int,
     window=None, softcap=None, max_seq_len=None,
-    impl: Optional[str] = None, block_q: int = 128, block_k: int = 128,
+    impl: Optional[str] = None, block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
 ):
     """One ring step of the DoP>1 ESP packed prefill: fold one striped KV
     chunk into the carried unnormalized (o, m, l) flash state with a single
@@ -186,7 +191,8 @@ def prefill_ring_chunk(
     (`striped.shard_offsets`) the kernel/banded fallback derive segment ids
     from; causal/window masks evaluate on global striped positions.
     ``carry=None`` starts an empty state (m=-inf).  Finalize after the last
-    step with ``o / l`` (l==0 rows are bucket padding)."""
+    step with ``o / l`` (l==0 rows are bucket padding).  Tiles as in
+    `prefill_packed`."""
     impl = impl or get_default_impl()
     check_fault("prefill_ring_chunk")
     dispatch_counts["prefill_ring_chunk"] += 1
@@ -201,7 +207,7 @@ def prefill_ring_chunk(
         return ref.packed_prefill_ring_chunk_banded(
             q, k, v, q_offsets, k_offsets, carry,
             q_shard=q_shard, k_shard=k_shard, n_shards=n_shards,
-            window=window, softcap=softcap, block_q=block_q,
+            window=window, softcap=softcap, block_q=block_q or ref.BAND,
             max_seq_len=max_seq_len,
         )
     return _pfp_ring_kernel(

@@ -58,6 +58,21 @@ variant's ``(block_q, H)`` stat blocks of the carried state, stay resident
 across the groups of one q block.  A group's columns are read and written
 through one-hot lane masks, so no dynamic lane slicing is needed.
 
+Tiles are chosen from the operands' shapes (`choose_tiles`), not fixed.
+Every grid step pays a cost that does not grow with its tile: the
+``q_per_kv`` heads of a group update their stat columns one after the
+other (select, cross-lane reduce, select), the pipeline's per-step
+overhead, and a K/V block DMA even for a causally skipped tile.  At
+128x128 a 16-head group spends about 3.6x its MXU time on that (a census
+of the compiled kernel's bundles, PERF.md).  So the chooser takes the
+first tile of `TILE_ORDER`, an order over {128, 256, 512}^2 ranked by
+that census (a static count, not a timing), whose blocks divide the shard
+length and whose reckoned VMEM (`tile_vmem_bytes`) fits `VMEM_BUDGET`;
+wide GQA groups get smaller tiles than MHA's 128-lane groups.  Where the
+tile needs more than Mosaic's default scoped VMEM the launch asks for the
+reckoned amount.  An explicit ``block_q``/``block_k`` wins (the
+interpret-mode tests pass 4-16 for multi-tile coverage).
+
 Deployment note: the in-process replay (LocalExecutor) passes static shard
 ids, so this Pallas kernel applies directly.  The shard_map mesh path
 (`core.esp.ring_packed_prefill_spmd`) has TRACED shard ids
@@ -92,6 +107,48 @@ def _block(n: int, want: int) -> int:
         blk //= 2
     assert blk >= 1, (n, want)
     return blk
+
+
+# (block_q, block_k) candidates, best first: fewest VLIW bundles per
+# 128x128 unit of a 16-head group in the compiled kernel on a v5e (the
+# census of PERF.md §5, a static count); the chooser takes the first that
+# fits VMEM_BUDGET
+TILE_ORDER = ((512, 512), (256, 512), (512, 256), (128, 256), (256, 256),
+              (128, 512), (512, 128), (256, 128), (128, 128))
+VMEM_BUDGET = 32 << 20  # bytes one launch's reckoned tiles may take
+SCOPED_VMEM = 16 << 20  # Mosaic's default scoped VMEM limit on a v5e
+
+
+def tile_vmem_bytes(block_q: int, block_k: int, *, q_per_kv: int, d: int,
+                    h: int, carry: bool, itemsize: int) -> int:
+    """VMEM one launch needs at a tile, as Mosaic allocates it on a v5e:
+    the double-buffered q, k, v and output blocks (and the carried o/m/l in
+    and out), the f32 scratch, twelve f32 ``(block_q, block_k)`` tiles for
+    the body's temporaries (scores, probabilities and masks of consecutive
+    heads of the unrolled group loop live at once), and 1 MiB for Mosaic's
+    own.  Stat columns pad to 128 lanes; ``itemsize`` is q/k/v's.  Read off
+    the least limit each tile compiles under, by bisection (PERF.md)."""
+    grp = q_per_kv * d
+    stat = block_q * (-(-h // 128) * 128) * 4  # one (block_q, H) f32 tile
+    out = block_q * grp * 4
+    blocks = 2 * (block_q * grp * itemsize + 2 * block_k * d * itemsize + out)
+    if carry:
+        blocks += 2 * (out + 4 * stat)
+    scratch = out + 2 * stat
+    return blocks + scratch + 12 * block_q * block_k * 4 + (1 << 20)
+
+
+def choose_tiles(t: int, *, q_per_kv: int, d: int, h: int, carry: bool,
+                 itemsize: int) -> tuple:
+    """(block_q, block_k) for a launch over a token axis of length ``t``:
+    the first of `TILE_ORDER`, each side halved to a divisor of ``t``
+    (`_block`), whose `tile_vmem_bytes` fits `VMEM_BUDGET`."""
+    for want_q, want_k in TILE_ORDER:
+        bq, bk = _block(t, want_q), _block(t, want_k)
+        if tile_vmem_bytes(bq, bk, q_per_kv=q_per_kv, d=d, h=h, carry=carry,
+                           itemsize=itemsize) <= VMEM_BUDGET:
+            return bq, bk
+    return _block(t, 128), _block(t, 128)
 
 
 def _seg_scalar(j, off_ref, n_seqs: int):
@@ -229,7 +286,12 @@ def _call(q, k, v, q_offsets, k_offsets, carry, *, name, q_shard, k_shard,
     t, h, d = q.shape
     kvh = k.shape[1]
     q_per_kv = h // kvh
-    block_q, block_k = _block(t, block_q), _block(t, block_k)
+    shape = dict(q_per_kv=q_per_kv, d=d, h=h, carry=carry is not None,
+                 itemsize=max(q.dtype.itemsize, k.dtype.itemsize))
+    auto_q, auto_k = choose_tiles(t, **shape)
+    block_q = auto_q if block_q is None else _block(t, block_q)
+    block_k = auto_k if block_k is None else _block(t, block_k)
+    vmem = tile_vmem_bytes(block_q, block_k, **shape)
     nq, nk = t // block_q, t // block_k
     n_seqs = int(q_offsets.shape[0]) - 1
     kernel = functools.partial(
@@ -269,6 +331,8 @@ def _call(q, k, v, q_offsets, k_offsets, carry, *, name, q_shard, k_shard,
     outs = pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interpret,
         name=name, metadata={"kernel": name},
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem)
+        if vmem > SCOPED_VMEM else None,
     )(jnp.asarray(q_offsets, jnp.int32), jnp.asarray(k_offsets, jnp.int32),
       *operands)
     return (outs[0].reshape(t, h, d),) + tuple(outs[1:])
@@ -282,8 +346,8 @@ def packed_flash_prefill(
     *,
     window: Optional[int] = None,
     softcap: Optional[float] = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """One ragged batched launch over the packed token axis; returns the
@@ -312,8 +376,8 @@ def packed_flash_prefill_ring_chunk(
     n_shards: int,
     window: Optional[int] = None,
     softcap: Optional[float] = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: bool = False,
 ):
     """One ring step: fold one striped KV chunk into the carried flash state

@@ -8,6 +8,8 @@ import jax.numpy as jnp
 
 from repro.models import attention as A
 
+BAND = 128  # the banded prefill fallbacks' q block (their band's unit)
+
 
 def serial_decode_oracle(model, params, prompt, n_decode: int) -> list:
     """Greedy token oracle for engine parity tests/demos: one serial
@@ -85,7 +87,7 @@ def packed_prefill_ref(
 
 
 def packed_prefill_banded(
-    q, k, v, seq_offsets, *, window=None, softcap=None, block_q=128,
+    q, k, v, seq_offsets, *, window=None, softcap=None, block_q=BAND,
     max_seq_len=None,
 ):
     """Production XLA fallback for packed ragged prefill.
@@ -180,7 +182,7 @@ def packed_prefill_ring_chunk_ref(
 
 def packed_prefill_ring_chunk_banded(
     q, k, v, q_offsets, k_offsets, carry, *, q_shard, k_shard, n_shards,
-    window=None, softcap=None, block_q=128, max_seq_len=None,
+    window=None, softcap=None, block_q=BAND, max_seq_len=None,
 ):
     """Production XLA fallback for one ring step of the striped packed
     prefill (the chunked analogue of `packed_prefill_banded`).

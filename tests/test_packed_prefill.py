@@ -81,6 +81,77 @@ def test_banded_fallback_matches_dense_oracle():
         np.testing.assert_allclose(banded, dense, atol=2e-5)
 
 
+def _grid(fn, *args):
+    """The grid of the one pallas_call in ``fn``'s jaxpr."""
+    (eqn,) = [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+              if e.primitive.name == "pallas_call"]
+    return eqn.params["grid_mapping"].grid
+
+
+# (t, q_per_kv, d): glm4-9b's 16-head groups, lwm-7b/qwen1.5-4b's MHA, and
+# the 3*2^j buckets whose tiles halve to a divisor
+CHOOSER_CASES = [(t, g, 128) for t in (16, 96, 1536, 2048, 4096, 12288, 16384)
+                 for g in (16, 1)] + [(16384, 8, 128), (3072, 4, 64)]
+
+
+@pytest.mark.parametrize("t,q_per_kv,d", CHOOSER_CASES)
+@pytest.mark.parametrize("carry", [False, True])
+def test_choose_tiles_divide_and_fit(t, q_per_kv, d, carry):
+    """The chosen tiles divide the token axis and their reckoned VMEM fits
+    the budget; an MHA group (one head) gets a tile at least as large as
+    the GQA group's at the same length."""
+    from repro.kernels import paged_flash_prefill as pfp
+
+    h = 32
+    bq, bk = pfp.choose_tiles(t, q_per_kv=q_per_kv, d=d, h=h, carry=carry,
+                              itemsize=2)
+    assert t % bq == 0 and t % bk == 0, (bq, bk)
+    assert pfp.tile_vmem_bytes(bq, bk, q_per_kv=q_per_kv, d=d, h=h,
+                               carry=carry, itemsize=2) <= pfp.VMEM_BUDGET
+    mq, mk = pfp.choose_tiles(t, q_per_kv=1, d=d, h=h, carry=carry,
+                              itemsize=2)
+    assert mq >= bq and mk >= bk, ((mq, mk), (bq, bk))
+
+
+def test_explicit_tile_wins():
+    """An explicit block_q/block_k sets the grid (halved to a divisor of
+    the bucket); None takes the chooser's tiles."""
+    from repro.kernels import paged_flash_prefill as pfp
+
+    q, k, v, off = (jnp.asarray(x) for x in _packed_case(0, [40, 50], 4, 2,
+                                                         32, bucket=96))
+    grid = lambda **kw: _grid(  # noqa: E731
+        lambda q, k, v, o: ops.prefill_packed(q, k, v, o, impl="interpret",
+                                              **kw), q, k, v, off)
+    assert grid(block_q=16, block_k=32) == (6, 2, 3)
+    assert grid(block_q=64, block_k=64) == (3, 2, 3)  # 64 halves to 32
+    bq, bk = pfp.choose_tiles(96, q_per_kv=2, d=32, h=4, carry=False,
+                              itemsize=4)
+    assert grid() == (96 // bq, 2, 96 // bk)
+    assert grid(block_k=16) == (96 // bq, 2, 6)
+
+
+@pytest.mark.parametrize("block_q,block_k", [(16, 32), (32, 16), (64, 64),
+                                             (None, None)])
+def test_packed_prefill_tiles_match_banded(block_q, block_k):
+    """Non-square tiles, tiles halved on a 3*2^j bucket (64 on 96 -> 32)
+    and the chosen tiles, with segment boundaries inside tiles, equal the
+    banded XLA fallback and the dense oracle."""
+    from repro.kernels import ref as kref
+
+    lens = [5, 1, 17, 9, 12, 30]  # boundaries 5, 6, 23, 32, 44, 74
+    q, k, v, off = (jnp.asarray(x) for x in _packed_case(4, lens, 4, 2, 32,
+                                                         bucket=96))
+    total = sum(lens)
+    out = np.asarray(ops.prefill_packed(q, k, v, off, impl="interpret",
+                                        block_q=block_q, block_k=block_k))
+    banded = np.asarray(kref.packed_prefill_banded(
+        q, k, v, off, block_q=8, max_seq_len=max(lens)))
+    dense = np.asarray(kref.packed_prefill_ref(q, k, v, off))
+    np.testing.assert_allclose(out[:total], banded[:total], atol=2e-5)
+    np.testing.assert_allclose(out[:total], dense[:total], atol=2e-5)
+
+
 def test_model_prefill_packed_matches_serial_prefill():
     """Model-level: one packed step reproduces per-request model.prefill —
     last-token logits AND the packed per-layer KV output."""

@@ -125,6 +125,41 @@ def test_ring_chunk_step_matches_chunk_oracle(impl):
             np.testing.assert_array_equal(np.isfinite(got), fin)
 
 
+@pytest.mark.parametrize("block_q,block_k", [(16, 32), (32, 16), (64, 64),
+                                             (None, None)])
+def test_ring_chunk_tiles_match_chunk_oracle(block_q, block_k):
+    """Both ring steps of shard 1 of a DoP-2 group, at non-square tiles,
+    tiles halved on a 3*2^j shard (64 on 96 -> 32) and the chosen tiles,
+    with segment boundaries inside tiles, equal the dense chunk oracle and
+    the banded XLA fallback."""
+    lens = [5, 1, 17, 9, 12, 30, 40, 20]  # 134 tokens, bucket 192
+    n = 2
+    q, k, v, off = _packed_case(5, lens, 4, 2, 32, bucket=192)
+    qs = jnp.asarray(q[1::n])  # shard 1 queries: 96 local slots
+    offs = [striped.shard_offsets(off, n, r) for r in range(n)]
+    carry = ref_carry = banded = None
+    for c in (1, 0):  # own chunk, then the rotated one
+        kc, vc = jnp.asarray(k[c::n]), jnp.asarray(v[c::n])
+        kw = dict(q_shard=1, k_shard=c, n_shards=n)
+        carry = ops.prefill_ring_chunk(
+            qs, kc, vc, offs[1], offs[c], carry, impl="interpret",
+            block_q=block_q, block_k=block_k, **kw)
+        banded = ops.prefill_ring_chunk(
+            qs, kc, vc, offs[1], offs[c], banded, impl="xla", block_q=8,
+            max_seq_len=max(lens), **kw)
+        ref_carry = kref.packed_prefill_ring_chunk_ref(
+            qs, kc, vc, jnp.asarray(off),
+            (jnp.zeros_like(carry[0]), jnp.full_like(carry[1], -jnp.inf),
+             jnp.zeros_like(carry[2])) if ref_carry is None else ref_carry,
+            **kw)
+        for got, want, alt in zip(carry, ref_carry, banded):
+            got, want, alt = (np.asarray(x) for x in (got, want, alt))
+            fin = np.isfinite(want)
+            np.testing.assert_allclose(got[fin], want[fin], atol=2e-5)
+            np.testing.assert_allclose(got[fin], alt[fin], atol=2e-5)
+            np.testing.assert_array_equal(np.isfinite(got), fin)
+
+
 def test_ring_banded_fallback_band_widths():
     """The banded XLA chunk fallback equals the dense chunk oracle for every
     static reach bound, including bands narrower than the shard axis."""

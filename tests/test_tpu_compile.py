@@ -1,4 +1,6 @@
-"""TPU v5e compile checks of the main-path Pallas kernels at lwm-7b widths.
+"""TPU v5e compile checks of the main-path Pallas kernels at lwm-7b widths,
+and of the prefill kernels at glm4-9b's too (16-head GQA groups, where the
+shape-chosen tiles need the most VMEM).
 
 Each case compiles for a described (not attached) ``v5e:2x2`` topology, so
 Mosaic's block-shape and VMEM rules are enforced at real widths without a
@@ -18,6 +20,10 @@ from repro.configs import get_config
 
 CFG = get_config("lwm-7b")
 H, KVH, D = CFG.n_heads, CFG.n_kv_heads, CFG.head_dim
+# (H, KVH, D) of the configurations the prefill kernels are compiled at
+WIDTHS = {name: (c.n_heads, c.n_kv_heads, c.head_dim)
+          for name, c in ((n, get_config(n)) for n in ("lwm-7b", "glm4-9b"))}
+PREFILL_CASES = [("lwm-7b", 2048), ("glm4-9b", 2048), ("glm4-9b", 16384)]
 
 
 @pytest.fixture(scope="module")
@@ -95,11 +101,13 @@ def test_paged_decode_compiles(one_chip, no_persistent_cache, page_size,
     )
 
 
-def test_packed_prefill_compiles(one_chip, no_persistent_cache):
-    """Packed ragged prefill at T = 2048 in the model's bf16."""
+@pytest.mark.parametrize("widths,t", PREFILL_CASES)
+def test_packed_prefill_compiles(one_chip, no_persistent_cache, widths, t):
+    """Packed ragged prefill in the model's bf16, at the tile the kernel
+    chooses for the shape."""
     from repro.kernels import ops
 
-    t = 2048
+    H, KVH, D = WIDTHS[widths]  # noqa: N806
     s = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
     _compile(
         lambda q, k, v, off: ops.prefill_packed(q, k, v, off, impl="pallas"),
@@ -108,12 +116,21 @@ def test_packed_prefill_compiles(one_chip, no_persistent_cache):
     )
 
 
-def test_ring_chunk_compiles(one_chip, no_persistent_cache):
-    """One ring step of a DoP-2 group (1024-token shard) with its float32
-    carried state."""
+@pytest.mark.parametrize("widths,tl,dtype", [
+    pytest.param("lwm-7b", 1024, jnp.bfloat16, id="lwm-7b-1024"),
+    pytest.param("glm4-9b", 2048, jnp.bfloat16, id="glm4-9b-2048"),
+    pytest.param("glm4-9b", 16384, jnp.bfloat16, id="glm4-9b-16384"),
+    pytest.param("lwm-7b", 1024, jnp.float32, id="lwm-7b-1024-float32"),
+    pytest.param("glm4-9b", 16384, jnp.float32, id="glm4-9b-16384-float32"),
+])
+def test_ring_chunk_compiles(one_chip, no_persistent_cache, widths, tl, dtype):
+    """One ring step of a DoP-2 group with its float32 carried state, at
+    the tile the kernel chooses for the shard: q/k/v in the model's bf16,
+    and in float32 (the four-chip smoke's weights), whose wider blocks
+    change the chosen tile and its VMEM limit."""
     from repro.kernels import ops
 
-    tl = 1024
+    H, KVH, D = WIDTHS[widths]  # noqa: N806
     s = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
 
     def fn(q, k, v, qo, ko, o, m, l):
@@ -123,20 +140,21 @@ def test_ring_chunk_compiles(one_chip, no_persistent_cache):
         )
 
     _compile(
-        fn, s((tl, H, D), jnp.bfloat16), s((tl, KVH, D), jnp.bfloat16),
-        s((tl, KVH, D), jnp.bfloat16), s((9,), jnp.int32),
+        fn, s((tl, H, D), dtype), s((tl, KVH, D), dtype),
+        s((tl, KVH, D), dtype), s((9,), jnp.int32),
         s((9,), jnp.int32), s((tl, H, D), jnp.float32),
         s((tl, H), jnp.float32), s((tl, H), jnp.float32),
     )
 
 
-def test_switched_ring_chunk_compiles_on_mesh(mesh4, no_persistent_cache):
-    """`esp.switched_ring_chunk` inside shard_map over 4 chips: every
-    rank-specialized branch of the `lax.switch` lowers to the kernel."""
+def _switched_ring_compiles(mesh4, widths, tl, dtype):
+    """Compile `esp.switched_ring_chunk` in shard_map over 4 chips, with
+    ``tl`` tokens a rank."""
     from repro.core import esp
     from repro.core.shmap import shmap
 
-    n, tl = 4, 512
+    H, KVH, D = WIDTHS[widths]  # noqa: N806
+    n = 4
     sh = NamedSharding(mesh4, P("data"))
 
     def body(q, k, v, off):
@@ -151,11 +169,25 @@ def test_switched_ring_chunk_compiles_on_mesh(mesh4, no_persistent_cache):
         out_specs=P("data"),
     )
     _compile(
-        fn, _spec(sh, (n * tl, H, D), jnp.bfloat16),
-        _spec(sh, (n * tl, KVH, D), jnp.bfloat16),
-        _spec(sh, (n * tl, KVH, D), jnp.bfloat16),
+        fn, _spec(sh, (n * tl, H, D), dtype),
+        _spec(sh, (n * tl, KVH, D), dtype),
+        _spec(sh, (n * tl, KVH, D), dtype),
         _spec(NamedSharding(mesh4, P(None)), (9,), jnp.int32),
     )
+
+
+def test_switched_ring_chunk_compiles_on_mesh(mesh4, no_persistent_cache):
+    """`esp.switched_ring_chunk` inside shard_map over 4 chips: every
+    rank-specialized branch of the `lax.switch` lowers to the kernel."""
+    _switched_ring_compiles(mesh4, "lwm-7b", 512, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("widths,tl", [("lwm-7b", 512), ("glm4-9b", 16384)])
+def test_switched_ring_chunk_compiles_on_mesh_float32(
+        mesh4, no_persistent_cache, widths, tl):
+    """The same in float32, as the four-chip smoke runs it: the wider
+    blocks change the chosen tile and its VMEM limit."""
+    _switched_ring_compiles(mesh4, widths, tl, jnp.float32)
 
 
 def test_switched_paged_partial_compiles_on_mesh(mesh4, no_persistent_cache):
